@@ -34,7 +34,7 @@ to end, seed vs current engine:
    silently regress onto the per-size chunked loop.
 6. **jax path** — the same churn scenario through the accelerator-native
    sweep backend (``Scenario(engine="jax")``, :mod:`repro.sim.jax_engine`,
-   Pallas victim-partition kernel per ``REPRO_PALLAS``). Seed side: the
+   Pallas victim-partition kernel per ``pallas_mode()``). Seed side: the
    *numpy sweep* (the equivalence oracle), not the reference pool — the
    lane gates the device step against the oracle it must match bit-for-bit
    (stats, interval times, config vectors) before timing. On 2-core CI
@@ -104,6 +104,7 @@ import numpy as np
 
 from benchmarks.common import DB_FM_FRACS, _representative_from, steady_from
 from repro.core.microbench import generate_microbench
+from repro.kernels.ops import pallas_mode
 from repro.core.trace import IntervalAccess, Trace
 from repro.core.tuner import TunaTuner, TunerConfig, build_database, scale_config
 from repro.core.watermark import WatermarkController
@@ -810,7 +811,7 @@ def run(report, params: BenchParams = FULL) -> dict:
         "admission_path_new_s": round(adm_new_t, 3),
         "admission_path_speedup": round(adm_speedup, 2),
         "admission_path_ratio": round(adm_ratio, 4),
-        "jax_pallas_mode": os.environ.get("REPRO_PALLAS", "auto"),
+        "jax_pallas_mode": pallas_mode(),
         "jax_migrations": int(jax_migrations),
         "jax_sweep_chunked_steps": int(jax_chunked),
         "jax_path_seed_s": round(jx_seed, 3),

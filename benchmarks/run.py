@@ -1,7 +1,9 @@
 """Benchmark driver: one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows. Kernel and roofline benches
-are included after the paper-reproduction set.
+are included after the paper-reproduction set. A failed suite is reported
+and the rest still run; the exit code is then non-zero (``--strict``
+stops at the first failure instead).
 
 Usage:  PYTHONPATH=src python -m benchmarks.run [filter ...]
 """
@@ -16,7 +18,7 @@ def _report(name: str, us_per_call: float, derived: str) -> None:
     print(f"{name},{us_per_call:.1f},{derived}", flush=True)
 
 
-def main() -> None:
+def main() -> int:
     filters = [a for a in sys.argv[1:] if not a.startswith("-")]
     from benchmarks import (
         fig1_motivation,
@@ -46,6 +48,7 @@ def main() -> None:
         ("kernels", kernel_bench),
     ]
     print("name,us_per_call,derived")
+    failed = []
     for key, mod in suites:
         if filters and not any(f in key for f in filters):
             continue
@@ -55,9 +58,14 @@ def main() -> None:
             _report(f"{key}/__suite__", (time.time() - t0) * 1e6, "ok")
         except Exception as e:  # keep the harness going; report the failure
             _report(f"{key}/__suite__", (time.time() - t0) * 1e6, f"FAIL:{e!r}")
+            failed.append(key)
             if "--strict" in sys.argv:
                 raise
+    if failed:
+        print(f"failed suites: {failed}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

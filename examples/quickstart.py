@@ -19,6 +19,7 @@ onto the deprecated `simulate`/`sweep_*` entry points.
 """
 
 import functools
+from pathlib import Path
 
 import numpy as np
 
@@ -35,6 +36,9 @@ from repro.sim.api import (
 from repro.sim.costmodel import OPTANE_LIKE
 from repro.sim.workloads import arrivals_trace, xsbench_trace
 from repro.timing import calibrate, timing_runner
+from repro.runtime.compile_cache import enable_compile_cache
+
+enable_compile_cache(Path(__file__).resolve().parents[1])
 
 print("== generating XSBench trace (real MC lookup kernel, page-instrumented)")
 trace = xsbench_trace(n_intervals=36, lookups=80_000)

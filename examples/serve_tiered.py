@@ -10,6 +10,8 @@ the HBM page budget every interval from live telemetry.
 Run:  PYTHONPATH=src python examples/serve_tiered.py
 """
 
+from pathlib import Path
+
 import numpy as np
 
 from repro.core import TunaTuner, TunerConfig, WatermarkController
@@ -17,6 +19,9 @@ from repro.core.perfdb import PerfDB, PerfRecord
 from repro.core.telemetry import ConfigVector
 from repro.serving import ContinuousBatcher, TieredPagedKV, TieredServer
 from repro.serving.kv_cache import KVPageConfig
+from repro.runtime.compile_cache import enable_compile_cache
+
+enable_compile_cache(Path(__file__).resolve().parents[1])
 
 TOTAL_PAGES, HBM_PAGES = 4096, 1024
 

@@ -8,12 +8,16 @@ Run:  PYTHONPATH=src python examples/train_lm.py [--arch qwen3-1.7b]
 
 import argparse
 import tempfile
+from pathlib import Path
 
 import jax
 
 from repro.configs import get_config
 from repro.launch.mesh import make_host_mesh
 from repro.launch.trainer import train
+from repro.runtime.compile_cache import enable_compile_cache
+
+enable_compile_cache(Path(__file__).resolve().parents[1])
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--arch", default="qwen3-1.7b")

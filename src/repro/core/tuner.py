@@ -309,6 +309,7 @@ def build_database(
     n_intervals: int = 20,
     max_rss_pages: int = 20_000,
     workers: int | None = None,
+    engine: str = "auto",
 ) -> PerfDB:
     """Offline: populate the performance database.
 
@@ -320,7 +321,9 @@ def build_database(
     against the shared fm-size vector. The planner produces each record's
     curve in one batched sweep pass per scenario and fans scenarios out
     across processes (``workers``; ``None`` = serial below 12 configs,
-    else one worker per core). The result is equivalent to running
+    else one worker per core). ``engine`` is each scenario's
+    :attr:`~repro.sim.api.Scenario.engine` (``"jax"`` runs the build on
+    the device sweep, in this process). The result is equivalent to running
     :func:`repro.sim.engine.run_trace` once per size — the engine
     equivalence tests pin this — at a fraction of the cost.
 
@@ -373,6 +376,7 @@ def build_database(
                     ),
                     name=name,
                     fast_only_at_full=True,
+                    engine=engine,
                 )
                 for name, cv in zip(scenario_names, configs)
             ],

@@ -1,62 +1,63 @@
 """Per-size demotion-ranking re-partition (segment scan) for the sweep.
 
 The JAX sweep backend (:mod:`repro.sim.jax_engine`) ranks every page once
-per interval by the shared demotion key — ``argsort`` over
-``(effective heat, page id)``, identical at every fast-memory size — and
-then each size must take the first ``demand[s]`` pages of that ranking
-that sit in *its* fast tier. In rank-order coordinates that is a segment
-scan per size row: a running count of fast-tier entries compared against
-the size's reclaim demand.
+per interval by the shared demotion key — ``(effective heat, page id)``,
+identical at every fast-memory size — and then each size must take the
+first ``demand[s]`` pages of that ranking that sit in *its* fast tier. In
+rank-order coordinates that is a segment scan per size row: a running
+count of fast-tier entries compared against the size's reclaim demand.
 
-XLA fuses the sort well but materializes the ``[n_sizes, rss]``
-cumulative sum as its own pass; the Pallas kernel here keeps one size row
-resident and emits the selection mask in a single sweep over it. On
-non-TPU backends (CPU CI) the kernel runs in interpreter mode, and when
-Pallas is unavailable or disabled the pure-``jnp`` fallback computes the
-identical mask — both paths are integer-exact, so backend choice can
-never perturb victim identities.
+A ``[n_sizes, rss]`` row block does not fit VMEM at real sizes (10.5 MB
+of int32 per size row at 10 GiB of 4 KiB pages), so the kernel tiles the
+rank axis: one sequential grid axis walks ``_TILE``-wide column tiles of
+all size rows at once, carrying each row's running fast count in VMEM
+scratch. Within a tile the inclusive prefix sum is one MXU product with an
+upper-triangular ones matrix (0/1 inputs are exact in bf16, and f32
+accumulation is exact far beyond ``_TILE``). The demands arrive as a
+scalar-prefetch operand in SMEM.
 
-Mode selection follows the ``REPRO_PALLAS`` convention of
-:mod:`repro.kernels.ops` (``auto`` | ``interpret`` | ``off``) but reads
-the environment *per call*, so test suites can monkeypatch the mode
-without re-importing the module.
+The kernel is compiled on TPU and runs in Pallas interpret mode on the
+CPU when ``REPRO_PALLAS=interpret``; :func:`_victim_partition_jnp` is the
+plain reference (and the CPU path otherwise). Both are integer-exact, so
+the choice never perturbs victim identities.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-_LANE = 128  # pad rows to the TPU lane multiple; zero-padding is inert
-
-
-def _mode() -> str:
-    return os.environ.get("REPRO_PALLAS", "auto")
+_TILE = 512  # rank positions per grid step (a multiple of the 128 lanes)
+_SUBLANE = 8
 
 
-def _use_pallas() -> bool:
-    mode = _mode()
-    if mode == "off":
-        return False
-    if mode == "interpret":
-        return True
-    return jax.default_backend() == "tpu"
+def _victim_partition_kernel(d_ref, f_ref, tri_ref, o_ref, carry_ref, dvec_ref):
+    """One column tile of every size row: select fast entries while the
+    row's running fast count stays within its demand."""
+    n_rows = f_ref.shape[0]
 
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        carry_ref[...] = jnp.zeros_like(carry_ref)
+        rows = lax.broadcasted_iota(jnp.int32, dvec_ref.shape, 0)
+        dvec = jnp.zeros(dvec_ref.shape, jnp.int32)
+        for s in range(n_rows):
+            dvec = jnp.where(rows == s, d_ref[s], dvec)
+        dvec_ref[...] = dvec
 
-def _interpret() -> bool:
-    return _mode() == "interpret" or jax.default_backend() != "tpu"
-
-
-def _victim_partition_kernel(d_ref, f_ref, o_ref):
-    """One size row: select fast entries while the running count <= demand."""
-    f = f_ref[...]  # [1, r_pad] int32: fast-tier membership in rank order
-    cum = jnp.cumsum(f, axis=1)
-    sel = (f > 0) & (cum <= d_ref[0, 0])
-    o_ref[...] = sel.astype(jnp.int32)
+    f = f_ref[...]  # [rows, _TILE] int32: fast-tier membership, rank order
+    local = jnp.dot(
+        f.astype(jnp.bfloat16), tri_ref[...], preferred_element_type=jnp.float32
+    ).astype(jnp.int32)
+    carry = carry_ref[...]
+    cum = local + carry
+    o_ref[...] = ((f > 0) & (cum <= dvec_ref[...])).astype(jnp.int32)
+    carry_ref[...] = carry + jnp.sum(f, axis=1, keepdims=True, dtype=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -64,50 +65,44 @@ def _victim_partition_pallas(
     fast01: jax.Array, demand: jax.Array, interpret: bool = False
 ) -> jax.Array:
     n_sizes, r = fast01.shape
-    r_pad = -(-r // _LANE) * _LANE
-    f = jnp.zeros((n_sizes, r_pad), dtype=jnp.int32)
-    f = f.at[:, :r].set(fast01.astype(jnp.int32))
-    d = demand.astype(jnp.int32).reshape(n_sizes, 1)
-    out = pl.pallas_call(
+    n_pad = -(-n_sizes // _SUBLANE) * _SUBLANE
+    r_pad = -(-r // _TILE) * _TILE
+    f = jnp.pad(fast01.astype(jnp.int32), ((0, n_pad - n_sizes), (0, r_pad - r)))
+    d = jnp.pad(demand.astype(jnp.int32), (0, n_pad - n_sizes))
+    # tri[i, j] = 1 for i <= j: (f @ tri)[:, j] is the inclusive prefix sum
+    tri = jnp.triu(jnp.ones((_TILE, _TILE), jnp.bfloat16))
+    # Mosaic lowers 32-bit index maps only: trace the kernel with x64 off
+    # even when the caller (the int64 sweep step) has it on
+    with jax.enable_x64(False):
+        out = _victim_partition_call(n_pad, r_pad, interpret)(d, f, tri)
+    return out[:n_sizes, :r]
+
+
+def _victim_partition_call(n_pad: int, r_pad: int, interpret: bool):
+    return pl.pallas_call(
         _victim_partition_kernel,
-        grid=(n_sizes,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda s: (s, 0)),
-            pl.BlockSpec((1, r_pad), lambda s: (s, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, r_pad), lambda s: (s, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_sizes, r_pad), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(r_pad // _TILE,),
+            in_specs=[
+                pl.BlockSpec((n_pad, _TILE), lambda j, d: (0, j)),
+                pl.BlockSpec((_TILE, _TILE), lambda j, d: (0, 0)),
+            ],
+            out_specs=pl.BlockSpec((n_pad, _TILE), lambda j, d: (0, j)),
+            scratch_shapes=[
+                pltpu.VMEM((n_pad, 1), jnp.int32),  # running fast count
+                pltpu.VMEM((n_pad, 1), jnp.int32),  # demand per row
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_pad, r_pad), jnp.int32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(d, f)
-    return out[:, :r]
+    )
 
 
 def _victim_partition_jnp(fast01: jax.Array, demand: jax.Array) -> jax.Array:
-    """Pure lax/jnp fallback: bit-identical selection mask."""
+    """Pure lax/jnp reference: bit-identical selection mask."""
     f = fast01.astype(jnp.int32)
     cum = jnp.cumsum(f, axis=1)
     sel = (f > 0) & (cum <= demand.astype(jnp.int32)[:, None])
     return sel.astype(jnp.int32)
-
-
-def victim_partition(fast01, demand):
-    """Victim selection mask per size row, in demotion-rank order.
-
-    ``fast01[s, i]`` is 1 when the page at rank position ``i`` is in size
-    ``s``'s fast tier; ``demand[s]`` is that size's reclaim demand. The
-    result marks, per row, the first ``demand[s]`` fast positions — the
-    pages :meth:`repro.tiering.page_pool.GlobalDemoteRank.walk` would
-    return. Dispatches to the Pallas kernel (interpret mode off-TPU) with
-    a jnp fallback; both are integer-exact so results never differ.
-    """
-    fast01 = jnp.asarray(fast01)
-    demand = jnp.asarray(demand)
-    if _use_pallas():
-        try:
-            return _victim_partition_pallas(
-                fast01, demand, interpret=_interpret()
-            )
-        except Exception:
-            if _mode() == "interpret":
-                raise
-    return _victim_partition_jnp(fast01, demand)

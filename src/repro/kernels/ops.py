@@ -1,8 +1,11 @@
 """Public kernel API with platform dispatch.
 
-On TPU (or with ``REPRO_PALLAS=interpret`` for CPU validation) the Pallas
-kernels are used; otherwise the jnp references. All model code calls
-through this module, so swapping the backend never touches model code.
+:func:`pallas_mode` is the one place the kernel mode is decided. On a TPU
+every Pallas kernel is compiled; a kernel that fails to lower raises. On
+the CPU the jnp references in :mod:`repro.kernels.ref` run, unless
+``REPRO_PALLAS=interpret`` asks for the kernels in Pallas interpret mode
+(how the test suite covers kernel code without a chip). All model code
+calls through this module, so the backend never leaks into model code.
 """
 
 from __future__ import annotations
@@ -14,65 +17,35 @@ import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 
-_MODE = os.environ.get("REPRO_PALLAS", "auto")  # auto | interpret | off
 
+def pallas_mode() -> str:
+    """``"compiled"`` on TPU; on other backends ``"interpret"`` when
+    ``REPRO_PALLAS=interpret``, else ``"off"`` (jnp references).
 
-def _use_pallas() -> bool:
-    if _MODE == "off":
-        return False
-    if _MODE == "interpret":
-        return True
-    return jax.default_backend() == "tpu"
-
-
-def _interpret() -> bool:
-    return _MODE == "interpret" or jax.default_backend() != "tpu"
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat ``shard_map``: newer jax exports ``jax.shard_map``
-    (replication checking via ``check_vma``); the pinned 0.4.x line only
-    has ``jax.experimental.shard_map.shard_map`` (same knob named
-    ``check_rep``). Resolve whichever this jax provides — replication
-    checking stays off either way (the LSE merge's psum outputs are
-    per-shard-identical by construction, which the checker cannot see).
+    Read per call, so tests can set the variable without re-importing.
     """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
+    env = os.environ.get("REPRO_PALLAS", "")
+    if env not in ("", "interpret"):
+        raise ValueError(f"REPRO_PALLAS={env!r}: the only setting is 'interpret'")
+    if jax.default_backend() == "tpu":
+        if env:
+            raise ValueError(
+                "REPRO_PALLAS=interpret is for the CPU backend; kernels are "
+                "always compiled on TPU"
             )
-        except TypeError:
-            # intermediate releases export jax.shard_map with the old
-            # check_rep spelling
-            return sm(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=False,
-            )
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
+        return "compiled"
+    return "interpret" if env else "off"
 
 
 # --------------------------------------------------------------- attention
 def attention(q, k, v, causal: bool = True):
     """Training/prefill attention; flash kernel on TPU, reference on CPU."""
-    if _use_pallas():
-        try:
-            from repro.kernels import flash_attention as fa
+    mode = pallas_mode()
+    if mode == "off":
+        return _ref.attention(q, k, v, causal=causal)
+    from repro.kernels import flash_attention as fa
 
-            return fa.flash_attention(
-                q, k, v, causal=causal, interpret=_interpret()
-            )
-        except Exception:
-            if _MODE == "interpret":
-                raise
-    return _ref.attention(q, k, v, causal=causal)
+    return fa.flash_attention(q, k, v, causal=causal, interpret=mode == "interpret")
 
 
 def decode_attention(q, k_cache, v_cache, valid_len):
@@ -137,78 +110,70 @@ def cp_decode_attention(q, k_cache, v_cache, valid_len, mesh,
         l_o = jnp.maximum(jnp.moveaxis(l_g, 1, 2), 1e-30)  # (b,1,h,1)
         return (o_g / l_o).astype(qb.dtype)
 
+    # replication checking off: the LSE merge's psum outputs are
+    # per-shard-identical by construction, which the checker cannot see
     qspec = P(batch_axis, None, None, None)
     kvspec = P(batch_axis, seq_axis, None, None)
     if quant:
-        fn = _shard_map(
+        fn = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(qspec, kvspec, kvspec, kvspec, kvspec, P()),
             out_specs=qspec,
+            check_vma=False,
         )
         return fn(q, k_cache, v_cache, k_scale, v_scale, valid_len)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda qb, kb, vb, vlen: local(qb, kb, vb, None, None, vlen),
         mesh=mesh,
         in_specs=(qspec, kvspec, kvspec, P()),
         out_specs=qspec,
+        check_vma=False,
     )
     return fn(q, k_cache, v_cache, valid_len)
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths):
-    if _use_pallas():
-        try:
-            from repro.kernels import paged_attention as pa
+    mode = pallas_mode()
+    if mode == "off":
+        return _ref.paged_decode_attention(q, k_pages, v_pages, page_table, lengths)
+    from repro.kernels import paged_attention as pa
 
-            return pa.paged_decode_attention(
-                q, k_pages, v_pages, page_table, lengths, interpret=_interpret()
-            )
-        except Exception:
-            if _MODE == "interpret":
-                raise
-    return _ref.paged_decode_attention(q, k_pages, v_pages, page_table, lengths)
+    return pa.paged_decode_attention(
+        q, k_pages, v_pages, page_table, lengths, interpret=mode == "interpret"
+    )
 
 
 def wkv6(r, k, v, w, u):
-    if _use_pallas():
-        try:
-            from repro.kernels import rwkv6_chunk as rk
+    mode = pallas_mode()
+    if mode == "off":
+        return _ref.wkv6(r, k, v, w, u)
+    from repro.kernels import rwkv6_chunk as rk
 
-            return rk.wkv6_chunked(r, k, v, w, u, interpret=_interpret())
-        except Exception:
-            if _MODE == "interpret":
-                raise
-    return _ref.wkv6(r, k, v, w, u)
+    return rk.wkv6_chunked(r, k, v, w, u, interpret=mode == "interpret")
 
 
 def migrate_pages(dst_pool, src_pool, dst_idx, src_idx):
-    if _use_pallas():
-        try:
-            from repro.kernels import page_migrate as pm
+    mode = pallas_mode()
+    if mode == "off":
+        return _ref.migrate_pages(dst_pool, src_pool, dst_idx, src_idx)
+    from repro.kernels import page_migrate as pm
 
-            return pm.migrate_pages(
-                dst_pool, src_pool, dst_idx, src_idx, interpret=_interpret()
-            )
-        except Exception:
-            if _MODE == "interpret":
-                raise
-    return _ref.migrate_pages(dst_pool, src_pool, dst_idx, src_idx)
+    return pm.migrate_pages(
+        dst_pool, src_pool, dst_idx, src_idx, interpret=mode == "interpret"
+    )
 
 
 def strided_probe(fast_arr, slow_arr, fast_idx, slow_idx, ai_iters: int):
-    if _use_pallas():
-        try:
-            from repro.kernels import strided_probe as sp
+    mode = pallas_mode()
+    if mode == "off":
+        return _ref.strided_probe(fast_arr, slow_arr, fast_idx, slow_idx, ai_iters)
+    from repro.kernels import strided_probe as sp
 
-            return sp.strided_probe(
-                fast_arr, slow_arr, fast_idx, slow_idx, ai_iters,
-                interpret=_interpret(),
-            )
-        except Exception:
-            if _MODE == "interpret":
-                raise
-    return _ref.strided_probe(fast_arr, slow_arr, fast_idx, slow_idx, ai_iters)
+    return sp.strided_probe(
+        fast_arr, slow_arr, fast_idx, slow_idx, ai_iters,
+        interpret=mode == "interpret",
+    )
 
 
 # ------------------------------------------------------------ bench hooks

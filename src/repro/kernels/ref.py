@@ -121,7 +121,7 @@ def migrate_pages(dst_pool, src_pool, dst_idx, src_idx):
 def strided_probe(fast_pool, slow_pool, fast_idx, slow_idx, ai_iters: int):
     """Tuna micro-benchmark reference: strided page loads from the two tier
     pools + ``ai_iters`` fused multiply-adds per loaded element; returns the
-    (1, page_elems) checksum accumulated over pages."""
+    ``(1, *page_shape)`` checksum accumulated over pages."""
     x = jnp.concatenate([fast_pool[fast_idx], slow_pool[slow_idx]], axis=0)
     x = x.astype(jnp.float32)
 

@@ -42,9 +42,21 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref, state_scr,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     w = w_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)  # (hd,)
+    u = u_ref[0].astype(jnp.float32)  # (1, hd)
 
-    cw = jnp.cumprod(w, axis=0)  # inclusive cumulative decay (C, hd)
+    # inclusive cumulative decay (C, hd): Mosaic has no cumprod, so the
+    # log-decays are prefix-summed by a lower-triangular ones product
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    lower = (s_idx <= t_idx).astype(jnp.float32)
+    logw = jnp.log(w)
+    cw = jnp.exp(
+        jax.lax.dot_general(
+            lower, logw, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+    )
     # decay from the chunk start to *before* token t: cw_t / w_t
     cw_in = cw / jnp.maximum(w, 1e-30)
     rq = r * cw_in  # query side carries decay from chunk start (exclusive)
@@ -54,13 +66,11 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref, state_scr,
     A = jax.lax.dot_general(
         rq, kk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (C, C): A[t, s] = Σ_k r_t cw_in_t kk_s
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, A.shape, 0)
-    s_idx = jax.lax.broadcasted_iota(jnp.int32, A.shape, 1)
     A = jnp.where(s_idx < t_idx, A, 0.0)
     o = jax.lax.dot_general(
         A, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    diag = jnp.sum(r * u[None, :] * k, axis=1, keepdims=True)  # (C,1)
+    diag = jnp.sum(r * u * k, axis=1, keepdims=True)  # (C,1)
     o = o + diag * v  # tuna: ignore[TUNA004] float-tolerance kernel, no bit-exact contract
 
     # ---- inter-chunk: contribution of the carried state
@@ -69,12 +79,22 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref, state_scr,
         rq, S, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
 
-    # ---- state update
-    cwC = cw[-1]  # (hd,)
-    k_scaled = kk * cwC[None, :]  # k_s ⊙ cw_C / cw_s
+    # ---- state update: the chunk's total decay cw_C, as a row (1, hd)
+    # for the key side and as a column (hd, 1) for the carried state
+    # (static slices and a ones product: Mosaic has no dynamic slice or
+    # lane-to-sublane reshape here)
+    cwC = cw[chunk - 1 : chunk, :]
+    k_scaled = kk * cwC  # k_s ⊙ cw_C / cw_s
+    cwC_col = jnp.exp(
+        jax.lax.dot_general(
+            logw, jnp.ones((chunk, 1), jnp.float32), (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+    )
     # tuna: ignore[TUNA004] decayed-state update: float-tolerance kernel,
     # no bit-exact-vs-numpy contract; FMA welcome
-    state_scr[...] = cwC[:, None] * S + jax.lax.dot_general(
+    state_scr[...] = cwC_col * S + jax.lax.dot_general(
         k_scaled, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
 
@@ -111,7 +131,8 @@ def wkv6_chunked(r, k, v, w, u, chunk: int = DEFAULT_CHUNK,
             pl.BlockSpec((1, 1, C, hd), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, C, hd), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, C, hd), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, hd), lambda b, h, c: (h, 0)),
+            # u as (H, 1, hd): the block's last two dims are the array's
+            pl.BlockSpec((1, 1, hd), lambda b, h, c: (h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, C, hd), lambda b, h, c: (b, h, c, 0)),
@@ -123,5 +144,5 @@ def wkv6_chunked(r, k, v, w, u, chunk: int = DEFAULT_CHUNK,
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         interpret=interpret,
-    )(rt, kt, vt, wt, u)
+    )(rt, kt, vt, wt, u.reshape(H, 1, hd))
     return jnp.moveaxis(o[:, :, :S], 1, 2), state

@@ -6,7 +6,9 @@ slow-tier arrays of Section 3.2) with a controlled number of arithmetic
 ops per loaded element (the AI knob). The page-id vectors are scalar
 prefetch operands; each grid step streams one page through VMEM and runs
 ``ai_iters`` fused multiply-adds per element, accumulating a checksum so
-nothing is dead-code eliminated.
+nothing is dead-code eliminated. A page is a ``(rows, 128)`` tile stack,
+one slot of the pools' untiled leading axis (a 4 KiB f32 page is
+``(8, 128)``).
 """
 
 from __future__ import annotations
@@ -46,34 +48,39 @@ def _probe_kernel(fast_idx_ref, slow_idx_ref, fast_ref, slow_ref, out_ref,
 @functools.partial(jax.jit, static_argnames=("ai_iters", "interpret"))
 def strided_probe(fast_pool, slow_pool, fast_idx, slow_idx, ai_iters: int,
                   interpret: bool = False):
-    """fast_pool/slow_pool (P, page_elems) f32; fast_idx (nf,), slow_idx
-    (ns,) int32 page ids. Returns the checksum (1, page_elems)."""
+    """fast_pool/slow_pool (P, rows, 128) f32; fast_idx (nf,), slow_idx
+    (ns,) int32 page ids. Returns the checksum (1, rows, 128)."""
+    if fast_pool.ndim != 3:
+        raise ValueError(
+            f"pool shape {fast_pool.shape}: give pages as (rows, 128)"
+        )
     nf, ns = fast_idx.shape[0], slow_idx.shape[0]
-    page_elems = fast_pool.shape[1]
+    blk = (1,) + fast_pool.shape[1:]
     kernel = functools.partial(_probe_kernel, n_fast=nf, ai_iters=ai_iters)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nf + ns,),
         in_specs=[
             pl.BlockSpec(
-                (1, page_elems),
-                lambda i, fi, si: (fi[jnp.minimum(i, fi.shape[0] - 1)], 0),
+                blk,
+                lambda i, fi, si: (fi[jnp.minimum(i, fi.shape[0] - 1)], 0, 0),
             ),
             pl.BlockSpec(
-                (1, page_elems),
+                blk,
                 lambda i, fi, si: (
                     si[jnp.clip(i - fi.shape[0], 0, si.shape[0] - 1)],
+                    0,
                     0,
                 ),
             ),
         ],
-        out_specs=pl.BlockSpec((1, page_elems), lambda i, fi, si: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((1, page_elems), jnp.float32)],
+        out_specs=pl.BlockSpec(blk, lambda i, fi, si: (0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM(blk, jnp.float32)],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, page_elems), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(blk, jnp.float32),
         interpret=interpret,
     )(fast_idx.astype(jnp.int32), slow_idx.astype(jnp.int32),
       fast_pool, slow_pool)

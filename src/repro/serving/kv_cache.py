@@ -45,6 +45,18 @@ class KVPageConfig:
     def bytes_per_page(self) -> int:
         return self.elems_per_page * jnp.dtype(self.dtype).itemsize
 
+    @property
+    def page_shape(self) -> tuple[int, int]:
+        """A page as ``(rows, 128)`` lanes: the layout the TPU tiles, so
+        a page is one slot of the pool's untiled leading axis."""
+        rows, rem = divmod(self.elems_per_page, 128)
+        if rem:
+            raise ValueError(
+                f"{self.elems_per_page} elements per page is not a multiple "
+                "of the 128 TPU lanes"
+            )
+        return (rows, 128)
+
 
 class TieredPagedKV:
     """Physical two-tier page store with slot allocators + page table."""
@@ -67,10 +79,10 @@ class TieredPagedKV:
             seed=seed,
         )
         self.policy = TPPPolicy(hot_thr=hot_thr)
-        flat = (cfg.elems_per_page,)
+        page = cfg.page_shape
         # physical pools: HBM (device array) and host (numpy)
-        self.hbm = jnp.zeros((hbm_capacity,) + flat, jnp.dtype(cfg.dtype))
-        self.host = np.zeros((total_pages,) + flat, dtype=jnp.dtype(cfg.dtype))
+        self.hbm = jnp.zeros((hbm_capacity,) + page, jnp.dtype(cfg.dtype))
+        self.host = np.zeros((total_pages,) + page, dtype=jnp.dtype(cfg.dtype))
         self.hbm_slot = np.full(total_pages, -1, np.int64)  # page -> hbm slot
         self._free_hbm = list(range(hbm_capacity - 1, -1, -1))
         self.migrated_in = 0
@@ -160,7 +172,9 @@ class TieredPagedKV:
     def write_tokens(self, pages: np.ndarray, data: jnp.ndarray) -> None:
         """Write new KV data into resident pages (decode appends)."""
         slots = self.hbm_view(pages)
-        self.hbm = self.hbm.at[slots].set(data.reshape(len(slots), -1))
+        self.hbm = self.hbm.at[slots].set(
+            data.reshape((len(slots),) + self.cfg.page_shape)
+        )
 
     def touch(self, pages: np.ndarray, counts=None) -> None:
         pages = np.atleast_1d(pages).astype(np.int64)

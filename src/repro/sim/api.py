@@ -68,7 +68,9 @@ unbatchable spec            per-size :func:`repro.sim.engine._simulate` — a
 
 Scenarios fan out across processes with ``concurrent.futures``
 (``parallelism=None`` keeps the database-build heuristic: serial below 12
-scenarios, else one worker per core), which is what absorbed the old
+scenarios, else one worker per core) — except ``engine="jax"`` scenarios,
+which always run in the calling process, the one that holds the
+accelerator. This is what absorbed the old
 ``build_database`` fan-out helper. The fan-out is resilient: a scenario
 that raises inside a worker is re-raised in the parent as
 :class:`ScenarioExecutionError` naming the scenario and echoing its spec;
@@ -185,6 +187,7 @@ import multiprocessing as mp
 import os
 import pickle
 import re
+import sys
 import uuid
 from dataclasses import asdict, dataclass, field
 from dataclasses import fields as dataclass_fields
@@ -1160,18 +1163,17 @@ def _validate_picklable(scenarios, policies) -> None:
                 ) from e
 
 
-def _resolve_start_method(requested, engines, available):
+def _resolve_start_method(requested, available, jax_loaded):
     """Pick the fan-out workers' multiprocessing start method.
 
     ``requested`` (``run()``'s ``mp_start_method``) wins when given and
-    available. Otherwise pure-numpy fan-outs keep the historical fork
-    preference — fork (where available) spares each worker the
-    interpreter + numpy re-import — while any ``engine="jax"`` scenario
-    flips the whole fan-out to spawn: forking after the XLA runtime has
-    initialized in the parent hands the child a copy of XLA's locked
-    thread state, which deadlocks or crashes it, and a spawned worker
-    re-imports a pristine runtime instead. Returns a method name from
-    ``available``, or ``None`` for the platform default.
+    available. Otherwise fork (where available) spares each worker the
+    interpreter + numpy re-import — unless this process has loaded JAX
+    (``jax_loaded``): forking after the XLA runtime has started hands the
+    child a copy of XLA's locked thread state, which deadlocks or crashes
+    it, so such a parent spawns workers that import repro without JAX.
+    Returns a method name from ``available``, or ``None`` for the
+    platform default.
     """
     if requested is not None:
         if requested not in available:
@@ -1180,7 +1182,7 @@ def _resolve_start_method(requested, engines, available):
                 f"platform (available: {list(available)})"
             )
         return requested
-    if "jax" in engines:
+    if jax_loaded:
         return "spawn" if "spawn" in available else None
     return "fork" if "fork" in available else None
 
@@ -1301,13 +1303,13 @@ def run(
     the RunSet result cache (see the module docstring's *Result caching*
     section): a directory under which the whole RunSet is memoized as its
     JSON document, keyed on the experiment spec echo + schema version.
-    ``mp_start_method`` pins the fan-out workers' multiprocessing start
-    method (``"fork"`` / ``"spawn"`` / ``"forkserver"``); ``None``
-    resolves it from the scenarios — pure-numpy experiments keep the
-    fork preference (cheap workers), while any ``engine="jax"`` scenario
-    switches the fan-out to spawn, because forking a parent whose XLA
-    runtime is already initialized is unsafe (see
-    :func:`_resolve_start_method`).
+    ``engine="jax"`` scenarios never fan out: they run in this process,
+    which holds the accelerator. ``mp_start_method`` pins the fan-out
+    workers' multiprocessing start method (``"fork"`` / ``"spawn"`` /
+    ``"forkserver"``); ``None`` keeps the fork preference (cheap workers)
+    unless this process has loaded JAX, which switches the fan-out to
+    spawn, because forking a parent whose XLA runtime is running is unsafe
+    (see :func:`_resolve_start_method`).
     """
     scenarios = list(experiment.scenarios)
     if not scenarios:
@@ -1442,28 +1444,34 @@ def run(
     ]
     if parallelism is None:
         parallelism = 1 if len(jobs) < 12 else (os.cpu_count() or 1)
-    parallelism = max(1, min(int(parallelism), len(jobs)))
-    outs = None
+    # engine="jax" scenarios need the accelerator, which belongs to this
+    # process: they always run here, whatever the parallelism; only the
+    # other scenarios fan out
+    fan = [
+        i for i, sc in enumerate(scenarios)
+        if getattr(sc, "engine", "auto") != "jax"
+    ]
+    parallelism = max(1, min(int(parallelism), len(fan)))
+    outs: list = [None] * len(jobs)
     if parallelism > 1:
-        _validate_picklable(scenarios, policies)
+        _validate_picklable([scenarios[i] for i in fan], policies)
         start_method = _resolve_start_method(
-            mp_start_method,
-            {getattr(sc, "engine", "auto") for sc in scenarios},
-            mp.get_all_start_methods(),
+            mp_start_method, mp.get_all_start_methods(), "jax" in sys.modules
         )
-        trapped = _fanout(jobs, parallelism, scenario_timeout, start_method)
-        if trapped is not None:
-            outs = []
-            for tag, val in trapped:
-                if tag == "err":
-                    name, echo, e = val
-                    raise ScenarioExecutionError(
-                        f"scenario {name!r} failed in a fan-out worker: "
-                        f"{type(e).__name__}: {e}\n  scenario spec: {echo}"
-                    ) from e
-                outs.append(val)
-    if outs is None:
-        outs = [_run_scenario_star(job) for job in jobs]
+        trapped = _fanout(
+            [jobs[i] for i in fan], parallelism, scenario_timeout, start_method
+        )
+        for i, (tag, val) in zip(fan, trapped or ()):
+            if tag == "err":
+                name, echo, e = val
+                raise ScenarioExecutionError(
+                    f"scenario {name!r} failed in a fan-out worker: "
+                    f"{type(e).__name__}: {e}\n  scenario spec: {echo}"
+                ) from e
+            outs[i] = val
+    for i, job in enumerate(jobs):
+        if outs[i] is None:
+            outs[i] = _run_scenario_star(job)
 
     runs, chunked = [], 0
     for records, c in outs:

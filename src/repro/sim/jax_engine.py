@@ -1,16 +1,29 @@
 """Accelerator-native sweep backend: the interval inner loop on JAX.
 
 The numpy sweep (:func:`repro.sim.sweep._sweep_run`) is the equivalence
-oracle; this module executes the *same* per-interval sequence — first-touch
-allocation, batched tier classification, heat decay, hot-set ranking, the
-vectorized TPP decision batch (:func:`repro.tiering.page_pool.
-_bulk_schedule_batch` as a :func:`jax.lax.while_loop`), per-size victim
-selection over the shared demotion ranking (a Pallas segment-scan kernel,
-:mod:`repro.kernels.demote_rank`, with a jnp fallback), and the
-promote/demote commit — as **one jitted device step per interval** over the
-stacked ``[n_sizes, rss]`` tier array, with the host keeping only what the
-paper's control plane actually needs per interval: integer counters for the
-cost model, watermarks, pool stats, profilers and tuners.
+oracle; this module executes the *same* per-interval sequence over the
+stacked ``[n_sizes, rss]`` tier array on the device, as two jitted steps
+per interval:
+
+* the **schedule step** — first-touch allocation, batched tier
+  classification, the per-size promotion-candidate filter and the
+  vectorized TPP decision batch (:func:`repro.tiering.page_pool.
+  _bulk_schedule_batch` as a :func:`jax.lax.while_loop`);
+* the **commit step** — per-size victim selection over the shared
+  demotion ranking (the :mod:`repro.kernels.demote_rank` Pallas
+  segment-scan kernel), interference detection, and the promote/demote
+  commit. It runs only in intervals where some size migrates.
+
+The size-independent work stays on the host, in the numpy sweep's own
+code: the ``float64`` heat recurrence (:class:`~repro.tiering.page_pool.
+LazyHeat`), the interval's hottest-first candidate order, the admission
+test, and the stable ``(effective heat, page id)`` demotion ranking
+(:class:`~repro.tiering.page_pool.GlobalDemoteRank`). The device receives
+that ranking as an int32 permutation plus int32 *tie groups* (equal
+effective heat ⇔ equal group, ordered like the heat), so it never holds a
+float. This is deliberate: a TPU has no native ``float64``, and a stable
+sort is minutes of TPU compile per shape, while the ranking is computed
+once per interval for every size.
 
 Exactness contract (pinned by ``tests/test_engine_equivalence.py``):
 
@@ -18,13 +31,11 @@ Exactness contract (pinned by ``tests/test_engine_equivalence.py``):
   and tuner decisions are **bit-exact** against the numpy sweep and the
   frozen ``ReferencePagePool`` lanes in every regime, including thrash;
 * the run is chunked-loop-free (``policy.chunked_steps`` stays zero);
-* ``float64`` everywhere (``jax.experimental.enable_x64``): the heat
-  recurrence ``heat*decay + touch`` is the same multiply sequence
-  :class:`~repro.tiering.page_pool.LazyHeat` performs, classification
-  GEMMs stay integer-valued below 2**53, and ``jnp.argsort(stable=True)``
-  matches ``np.argsort(kind="stable")`` tie order.
+* on the device everything is integer: int8 tiers, int32 positions and
+  tie groups, and int64 (``jax.enable_x64``) for access sums and the
+  schedule's counters, which the TPU emulates exactly.
 
-Thrash-regime victim resolution stays host-side by design: the device step
+Thrash-regime victim resolution stays host-side by design: the commit step
 detects interference (reclaim demand reaching into same-step promotions)
 per size and commits a provisional fast-path state; interfering sizes are
 then corrected through the *same* host resolver the numpy sweep uses
@@ -39,9 +50,9 @@ backend; thrash-guard's stateful host hooks are excluded), the run must be
 fault-free, and every interval's page ids must be unique — duplicate ids
 raise loudly instead of silently degrading to the chunked path.
 
-Pallas mode follows ``REPRO_PALLAS`` (``auto`` | ``interpret`` | ``off``),
-resolved per run: interpreter mode on CPU CI, compiled kernel on TPU, jnp
-fallback when disabled.
+The victim partition follows :func:`repro.kernels.ops.pallas_mode`: the
+compiled kernel on TPU, the kernel in interpret mode on the CPU under
+``REPRO_PALLAS=interpret``, and the jnp reference otherwise.
 """
 
 from __future__ import annotations
@@ -53,16 +64,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.kernels.demote_rank import (
-    _interpret,
-    _use_pallas,
     _victim_partition_jnp,
     _victim_partition_pallas,
 )
+from repro.kernels.ops import pallas_mode
 from repro.sim.costmodel import absorb_cache, effective_mlp, interval_time
+from repro.sim.sweep import _fold_heat, _hot_sorted
 from repro.tiering.page_pool import (
+    GlobalDemoteRank,
     LazyHeat,
     Tier,
     TieredPagePool,
@@ -72,7 +83,7 @@ from repro.tiering.policy import PolicyOutcome
 
 _FAST = int(Tier.FAST)
 _SLOW = int(Tier.SLOW)
-_BIG = 2**62  # hot-sort key for non-candidates: sorts after every -touch
+_NO_GROUP = np.iinfo(np.int32).max  # tie group of "no winner"
 
 
 def _bucket(n: int, floor: int = 128) -> int:
@@ -80,30 +91,10 @@ def _bucket(n: int, floor: int = 128) -> int:
     return max(floor, 1 << max(0, int(n - 1).bit_length()))
 
 
-def _pad_i64(arr: np.ndarray, size: int, fill: int) -> np.ndarray:
-    out = np.full(size, fill, dtype=np.int64)
+def _pad(arr: np.ndarray, size: int, fill, dtype) -> np.ndarray:
+    out = np.full(size, fill, dtype=dtype)
     out[: arr.size] = arr
     return out
-
-
-def _pad_f64(arr: np.ndarray, size: int) -> np.ndarray:
-    out = np.zeros(size, dtype=np.float64)
-    out[: arr.size] = arr
-    return out
-
-
-@jax.jit
-def _decay_heat(heat, decay):
-    """``heat * decay`` as its own executable, deliberately.
-
-    Inside the interval step XLA's CPU emitter contracts
-    ``heat * decay + touch`` into an FMA (1-ULP difference from numpy's
-    separate multiply-then-add; ``optimization_barrier`` and excess-
-    precision flags do not stop it — fusions clone the multiply). Keeping
-    the multiply in a separate executable leaves the step with a pure
-    add, which cannot contract, restoring bit-exact heat.
-    """
-    return heat * decay
 
 
 def _schedule_loop(free, fastc, minf, lowf, highf, kswapd, n_cand):
@@ -178,152 +169,130 @@ def _schedule_loop(free, fastc, minf, lowf, highf, kswapd, n_cand):
 
 
 @functools.lru_cache(maxsize=None)
-def _build_step(
-    n_sizes: int,
-    num_pages: int,
-    p_pad: int,
-    hot_thr: int,
-    admit_margin,  # None for plain TPP, float for the admission backend
-    promote_batch,  # None = unbounded
-    use_pallas: bool,
-    interpret: bool,
-):
-    """Compile one interval step for a (shape, policy-mode) combination.
+def _build_schedule_step(hot_thr: int, promote_batch):
+    """The allocation + classification + schedule step for one policy
+    mode (``promote_batch=None`` = unbounded). Shapes are jit's to key."""
 
-    Cached per combination: traces repeat their padded-interval buckets,
-    so a run compiles a handful of variants and reuses them.
-    """
-
-    def step(
-        tier, decayed, pages_p, counts_f, rep_f, touches_p, valid, is_new,
-        n_fast, free, fastc, minf, lowf, highf, kswapd,
+    def schedule_step(
+        tier, new_rank, n_fast, pages_p, counts_p, rep_p, valid, hot_p,
+        hot_valid, hot_ok, free, fastc, minf, lowf, highf, kswapd,
     ):
-        rows = jnp.arange(n_sizes)[:, None]
         # --- first-touch allocation: per size a prefix of the new pages
         # (access order) goes fast, the rest slow — n_fast is the host's
-        # watermark-budget prefix length
-        new_rank = jnp.cumsum(is_new.astype(jnp.int64)) - 1
-        alloc_ids = jnp.where(is_new, pages_p, num_pages)
-        alloc_vals = jnp.where(
-            new_rank[None, :] < n_fast[:, None], _FAST, _SLOW
-        ).astype(tier.dtype)
-        tier = tier.at[rows, alloc_ids[None, :]].set(alloc_vals, mode="drop")
-        # --- the interval's dense touch counters (page ids are unique per
-        # interval — validated by the caller — so add == set)
-        touch_dense = (
-            jnp.zeros(num_pages, jnp.int64)
-            .at[pages_p]
-            .add(touches_p, mode="drop")
+        # watermark-budget prefix length, new_rank a page's place in it
+        alloc = jnp.where(new_rank[None, :] < n_fast[:, None], _FAST, _SLOW)
+        tier = jnp.where(
+            (new_rank >= 0)[None, :], alloc.astype(tier.dtype), tier
         )
-        # --- batched tier classification; float64 GEMM over integer
-        # values < 2**53 is exact regardless of summation order
-        gath = tier[:, pages_p]  # pad ids clamp; masked via `valid`
-        fast_m = (gath == _FAST) & valid[None, :]
-        warm_f = (rep_f < float(hot_thr)).astype(jnp.float64)
-        cols = jnp.stack([counts_f, rep_f, warm_f, rep_f * warm_f], axis=1)
-        sums = (fast_m.astype(jnp.float64) @ cols).astype(jnp.int64)
-        # --- effective heat: the interval-frozen demotion key, which is
-        # also the post-fold heat (heat*decay + touches) — computed once;
-        # ``decayed`` arrives pre-multiplied (see _decay_heat) so this is
-        # a pure, contraction-free add
-        eff_all = decayed + touch_dense
-        # --- hot candidates, hottest-first stable order: sorting
-        # (-touches | BIG) reproduces the numpy counting-sort/argsort tie
-        # order (descending touches, position-stable)
-        hot = valid & (touches_p >= hot_thr)
-        key = jnp.where(hot, -touches_p, _BIG)
-        perm = jnp.argsort(key, stable=True)
-        hot_pos = key[perm] < _BIG  # prefix mask over sorted positions
-        hot_ids = jnp.where(hot_pos, pages_p[perm], num_pages)
-        eff_h = eff_all[jnp.clip(hot_ids, 0, num_pages - 1)]
-        gh = tier[:, hot_ids]  # pad ids clamp; masked via hot_pos
-        slow_cand = (gh == _SLOW) & hot_pos[None, :]
-        if admit_margin is None:
-            admitted = slow_cand
-        else:
-            # AdmissionTPPPolicy._admit: trace-pure, size-independent
-            admitted = slow_cand & (eff_h >= admit_margin * hot_thr)[None, :]
-        rejected = (
-            slow_cand.sum(axis=1).astype(jnp.int64)
-            - admitted.sum(axis=1).astype(jnp.int64)
+        # --- batched tier classification of the touched pages: exact
+        # integer sums (the numpy sweep's integer-valued float GEMM)
+        fast_m = (jnp.take(tier, pages_p, axis=1) == _FAST) & valid[None, :]
+        fast_w = fast_m & (rep_p < hot_thr)[None, :]
+
+        def masked_sum(mask, vals):
+            return jnp.sum(jnp.where(mask, vals[None, :], 0), axis=1)
+
+        sums = jnp.stack(
+            [
+                masked_sum(fast_m, counts_p),
+                masked_sum(fast_m, rep_p),
+                jnp.sum(fast_w, axis=1, dtype=jnp.int64),
+                masked_sum(fast_w, rep_p),
+            ],
+            axis=1,
+        )
+        # --- promotion candidates: each size's slow-tier subset of the
+        # host's hottest-first order, filtered by the admission test
+        slow_cand = (jnp.take(tier, hot_p, axis=1) == _SLOW) & hot_valid[None, :]
+        admitted = slow_cand & hot_ok[None, :]
+        rejected = jnp.sum(slow_cand, axis=1, dtype=jnp.int64) - jnp.sum(
+            admitted, axis=1, dtype=jnp.int64
         )
         if promote_batch is not None:
-            arank = jnp.cumsum(admitted.astype(jnp.int64), axis=1)
+            arank = jnp.cumsum(admitted.astype(jnp.int32), axis=1)
             admitted = admitted & (arank <= promote_batch)
-        n_cand = admitted.sum(axis=1).astype(jnp.int64)
+        n_cand = jnp.sum(admitted, axis=1, dtype=jnp.int64)
         # --- the promote/reclaim schedule for every size at once
         pm_pr, pm_de, pm_fail, direct_total, events, d_demand = (
             _schedule_loop(free, fastc, minf, lowf, highf, kswapd, n_cand)
         )
         # --- winners: the first pm_pr admitted candidates per size
-        wrank = jnp.cumsum(admitted.astype(jnp.int64), axis=1)
+        wrank = jnp.cumsum(admitted.astype(jnp.int32), axis=1)
         win_mask = admitted & (wrank <= pm_pr[:, None])
-        win_eff_min = jnp.min(
-            jnp.where(win_mask, eff_h[None, :], jnp.inf), axis=1
-        )
-        # --- victims: first d_demand fast pages per size in the shared
-        # (effective heat, page id) ranking — the segment-scan kernel
-        order = jnp.argsort(eff_all, stable=True)
-        ranked = tier[:, order]
-        fast01 = (ranked == _FAST).astype(jnp.int32)
-        if use_pallas:
-            vic_sel = _victim_partition_pallas(
-                fast01, d_demand, interpret=interpret
-            )
-        else:
-            vic_sel = _victim_partition_jnp(fast01, d_demand)
-        vcount = vic_sel.sum(axis=1).astype(jnp.int64)
-        posr = jnp.arange(num_pages)
-        last_pos = jnp.max(
-            jnp.where(vic_sel > 0, posr[None, :], -1), axis=1
-        )
-        eff_ranked = eff_all[order]
-        last_eff = jnp.where(
-            last_pos >= 0, eff_ranked[jnp.clip(last_pos, 0)], -jnp.inf
-        )
-        # interference: demand reaching into same-step promotions — the
-        # exact _try_bulk_step precondition (ties count as interference)
-        interf = (d_demand > 0) & (
-            (vcount < d_demand)
-            | ((pm_pr > 0) & (win_eff_min <= last_eff))
-        )
-        # --- provisional commit (exact for non-interfering sizes; the
-        # host patches interfering rows' tier identity afterwards)
-        rank_inv = jnp.zeros(num_pages, jnp.int64).at[order].set(posr)
-        ranked_new = jnp.where(
-            vic_sel > 0, jnp.full((), _SLOW, tier.dtype), ranked
-        )
-        tier = jnp.take(ranked_new, rank_inv, axis=1)
-        win_ids = jnp.where(win_mask, hot_ids[None, :], num_pages)
-        tier = tier.at[rows, win_ids].set(
-            jnp.full((), _FAST, tier.dtype), mode="drop"
-        )
         counters = jnp.stack(
             [pm_pr, pm_de, pm_fail, direct_total, events, d_demand,
              rejected, n_cand]
         )
-        return (
-            tier, eff_all, sums, counters, interf, vic_sel, order, hot_ids,
-            win_mask,
-        )
+        return tier, sums, counters, win_mask
 
-    return jax.jit(step)
+    return jax.jit(schedule_step)
+
+
+@functools.lru_cache(maxsize=None)
+def _build_commit_step(mode: str):
+    """The victim-selection + commit step for one Pallas mode."""
+
+    def commit_step(
+        tier, order, rank_inv, grp, hot_slot, win_mask, hot_grp, counters
+    ):
+        pm_pr, d_demand = counters[0], counters[5]
+        # --- victims: first d_demand fast pages per size in the shared
+        # (effective heat, page id) ranking — the segment-scan kernel
+        ranked = jnp.take(tier, order, axis=1, mode="clip")
+        fast01 = (ranked == _FAST).astype(jnp.int32)
+        if mode == "off":
+            vic_sel = _victim_partition_jnp(fast01, d_demand)
+        else:
+            vic_sel = _victim_partition_pallas(
+                fast01, d_demand, interpret=mode == "interpret"
+            )
+        vsel = vic_sel > 0
+        vcount = jnp.sum(vsel, axis=1, dtype=jnp.int64)
+        pos = jnp.arange(order.shape[0], dtype=jnp.int32)
+        last_pos = jnp.max(jnp.where(vsel, pos[None, :], -1), axis=1)
+        last_grp = jnp.where(
+            last_pos >= 0, jnp.take(grp, jnp.maximum(last_pos, 0)), -1
+        )
+        win_grp_min = jnp.min(
+            jnp.where(win_mask, hot_grp[None, :], _NO_GROUP), axis=1
+        )
+        # interference: demand reaching into same-step promotions — the
+        # exact _try_bulk_step precondition (ties count as interference)
+        interf = (d_demand > 0) & (
+            (vcount < d_demand) | ((pm_pr > 0) & (win_grp_min <= last_grp))
+        )
+        # --- provisional commit in rank order (exact for non-interfering
+        # sizes; the host patches interfering rows' tier identity after)
+        win_ranked = jnp.take(
+            win_mask, hot_slot, axis=1, mode="fill", fill_value=False
+        )
+        ranked = jnp.where(vsel, jnp.int8(_SLOW), ranked)
+        ranked = jnp.where(win_ranked, jnp.int8(_FAST), ranked)
+        tier = jnp.take(ranked, rank_inv, axis=1, mode="clip")
+        return tier, interf, vsel
+
+    return jax.jit(commit_step)
 
 
 @jax.jit
-def _fix_row(tier, row, to_fast, to_slow):
+def _row(x, s):
+    """One size row of a stacked device array (``s`` traced: one compile)."""
+    return lax.dynamic_index_in_dim(x, s, axis=0, keepdims=False)
+
+
+@jax.jit
+def _fix_row(tier, row, fix):
     """Patch one interfering size's tier identity after host resolution.
 
-    ``to_fast`` are walked victims the resolver did *not* demote,
-    ``to_slow`` are same-step promotions it did; both are padded with the
-    out-of-range id ``num_pages`` (dropped by the scatter)."""
-    tier = tier.at[row, to_fast].set(
-        jnp.full((), _FAST, tier.dtype), mode="drop"
-    )
-    tier = tier.at[row, to_slow].set(
-        jnp.full((), _SLOW, tier.dtype), mode="drop"
-    )
-    return tier
+    ``fix`` is a dense per-page int8 vector: 1 for walked victims the
+    resolver did *not* demote (back to fast), 2 for same-step promotions
+    it did (back to slow), 0 elsewhere. Dense keeps one executable per
+    tier shape (a scatter of variable-length id lists compiles once per
+    length bucket)."""
+    old = lax.dynamic_index_in_dim(tier, row, axis=0, keepdims=False)
+    new = jnp.where(fix == 1, jnp.int8(_FAST), old)
+    new = jnp.where(fix == 2, jnp.int8(_SLOW), new)
+    return lax.dynamic_update_index_in_dim(tier, new, row, axis=0)
 
 
 def _require_jax_runnable(trace, policy, faults) -> None:
@@ -346,6 +315,16 @@ def _require_jax_runnable(trace, policy, faults) -> None:
                 f"engine='jax' requires unique page ids per interval; "
                 f"interval {i} of trace '{trace.name}' repeats ids"
             )
+
+
+def _tie_groups(g: GlobalDemoteRank) -> np.ndarray:
+    """Number the tie classes of effective heat in rank order (int32), so
+    that comparing groups on the device is comparing heats, ties
+    included."""
+    eff_sorted = g.eff[g.order]
+    grp = np.zeros(g.order.size, dtype=np.int32)
+    np.cumsum(eff_sorted[1:] != eff_sorted[:-1], out=grp[1:])
+    return grp
 
 
 def _sweep_run_jax(
@@ -372,15 +351,14 @@ def _sweep_run_jax(
     cap = int(hw_capacity_pages or trace.rss_pages)
     hot_thr = policy.hot_thr
     admit_margin = getattr(policy, "admit_margin", None)
-    admit_margin = None if admit_margin is None else float(admit_margin)
-    promote_batch = policy.promote_batch
-    use_pallas = _use_pallas()
-    interpret = _interpret()
+    schedule_step = _build_schedule_step(hot_thr, policy.promote_batch)
+    commit_step = _build_commit_step(pallas_mode())
 
-    with enable_x64():
+    with jax.enable_x64(True):
         # host-side slice pools: watermarks, stats, rss — the control
-        # plane the profilers/tuners read. Tier rows live on device for
-        # the run and are imported back at the end.
+        # plane the profilers/tuners read — plus the shared heat and
+        # touch counters. Tier rows live on device for the run and are
+        # imported back at the end.
         tier_b = np.full((n_sizes, num_pages), int(Tier.UNALLOCATED), np.int8)
         halflife_decay = 0.5 ** (1.0 / 2.0)
         heat = LazyHeat(num_pages, halflife_decay)
@@ -409,8 +387,10 @@ def _sweep_run_jax(
                     tuner.bind_pool(pool, cap)
 
         dev_tier = jnp.asarray(TieredPagePool._export_tier_stack(pools))
-        dev_heat = jnp.zeros(num_pages, dtype=jnp.float64)
         allocated = tier_b[0] != int(Tier.UNALLOCATED)
+        # device constants, made on first use: "no new page" allocation
+        # ranks, and the identity ranking of intervals that only promote
+        no_new = identity = None
 
         n_intervals = len(trace)
         times = np.zeros((n_sizes, n_intervals), dtype=np.float64)
@@ -440,7 +420,7 @@ def _sweep_run_jax(
             # fast-prefix length is each size's watermark budget
             new_mask = ~allocated[pages] if pages.size else np.zeros(0, bool)
             n_new = int(np.count_nonzero(new_mask))
-            n_fast_arr = np.zeros(n_sizes, dtype=np.int64)
+            n_fast_arr = np.zeros(n_sizes, dtype=np.int32)
             if n_new:
                 for s, pool in enumerate(pools):
                     budget = max(0, pool.fast_free - pool.watermarks.low_free)
@@ -451,6 +431,22 @@ def _sweep_run_jax(
                     pool._rss_pages += n_new
                     pool._fast_used += int(nf)
                 allocated[pages[new_mask]] = True
+                new_rank = np.full(num_pages, -1, dtype=np.int32)
+                new_rank[pages[new_mask]] = np.arange(n_new, dtype=np.int32)
+            else:
+                if no_new is None:
+                    no_new = jnp.full(num_pages, -1, dtype=jnp.int32)
+                new_rank = no_new
+            # --- size-independent host work: the interval's touches,
+            # hottest-first candidates and their admission test
+            interval_touch[pages] += touches  # ids are unique per interval
+            hot = _hot_sorted(pages, touches, hot_thr)
+            if admit_margin is None:
+                hot_ok = np.ones(hot.size, dtype=bool)
+            else:
+                # AdmissionTPPPolicy._admit: trace-pure, size-independent
+                eff_hot = heat.lookahead(hot) + interval_touch[hot]
+                hot_ok = eff_hot >= float(admit_margin) * hot_thr
             # --- schedule inputs: post-allocation free/fast state
             free_a = np.empty(n_sizes, dtype=np.int64)
             fastc_a = np.empty(n_sizes, dtype=np.int64)
@@ -466,69 +462,72 @@ def _sweep_run_jax(
                 lowf_a[s] = wm.low_free
                 highf_a[s] = wm.high_free
                 kswapd_a[s] = pool.kswapd_batch
-            # --- one jitted device step for the whole size vector
             p_pad = _bucket(pages.size)
-            step = _build_step(
-                n_sizes, num_pages, p_pad, hot_thr, admit_margin,
-                promote_batch, use_pallas, interpret,
-            )
-            valid = np.zeros(p_pad, dtype=bool)
-            valid[: pages.size] = True
-            is_new = np.zeros(p_pad, dtype=bool)
-            is_new[: pages.size] = new_mask
-            (
-                dev_tier, dev_heat, sums_d, counters_d, interf_d, vic_sel_d,
-                order_d, hot_ids_d, win_mask_d,
-            ) = step(
+            tier_alloc, sums_d, counters_d, win_mask_d = schedule_step(
                 dev_tier,
-                _decay_heat(dev_heat, halflife_decay),
-                _pad_i64(pages, p_pad, num_pages),
-                _pad_f64(counts_mem.astype(np.float64), p_pad),
-                _pad_f64(rep.astype(np.float64), p_pad),
-                _pad_i64(touches, p_pad, 0),
-                valid,
-                is_new,
+                new_rank,
                 n_fast_arr,
+                _pad(pages, p_pad, 0, np.int32),
+                _pad(counts_mem, p_pad, 0, np.int64),
+                _pad(rep, p_pad, 0, np.int64),
+                _pad(np.ones(pages.size, bool), p_pad, False, bool),
+                _pad(hot, p_pad, 0, np.int32),
+                _pad(np.ones(hot.size, bool), p_pad, False, bool),
+                _pad(hot_ok, p_pad, False, bool),
                 free_a, fastc_a, minf_a, lowf_a, highf_a, kswapd_a,
             )
             counters = np.asarray(counters_d)
             (pm_pr, pm_de, pm_fail, direct_total, events, d_demand,
              rejected, n_cand) = counters
-            interf = np.asarray(interf_d)
-            # --- thrash regime: resolve interfering sizes' victim
-            # identities with the numpy sweep's own host resolver and
-            # patch the device tier (counters are schedule-determined
-            # and already exact)
-            if interf.any():
-                eff_np = np.asarray(dev_heat)  # == eff_all this interval
-                order_np = np.asarray(order_d)
-                vic_sel_np = np.asarray(vic_sel_d)
-                hot_ids_np = np.asarray(hot_ids_d)
-                win_mask_np = np.asarray(win_mask_d)
+            dev_tier = tier_alloc
+            if pm_pr.any() or d_demand.any():
+                # --- the shared demotion ranking, only when some size
+                # demotes (promote-only intervals commit in page order)
+                if d_demand.any():
+                    rk = GlobalDemoteRank(heat.lookahead_dense() + interval_touch)
+                    grp_np = _tie_groups(rk)
+                    order = jnp.asarray(rk.order.astype(np.int32))
+                    rank_inv = jnp.asarray(rk.rank.astype(np.int32))
+                    grp = jnp.asarray(grp_np)
+                    hot_rank = rk.rank[hot]
+                    hot_grp = _pad(grp_np[hot_rank], p_pad, _NO_GROUP, np.int32)
+                else:
+                    rk = None
+                    if identity is None:
+                        identity = jnp.arange(num_pages, dtype=jnp.int32)
+                    order = rank_inv = grp = identity
+                    hot_rank = hot
+                    hot_grp = np.full(p_pad, _NO_GROUP, dtype=np.int32)
+                hot_slot = np.full(num_pages, p_pad, dtype=np.int32)
+                hot_slot[hot_rank] = np.arange(hot.size, dtype=np.int32)
+                dev_tier, interf_d, vsel_d = commit_step(
+                    tier_alloc, order, rank_inv, grp, hot_slot, win_mask_d,
+                    hot_grp, counters_d,
+                )
+                interf = np.asarray(interf_d)
+                # --- thrash regime: resolve interfering sizes' victim
+                # identities with the numpy sweep's own host resolver and
+                # patch the device tier (counters are schedule-determined
+                # and already exact)
                 for s in np.flatnonzero(interf):
-                    victims = order_np[vic_sel_np[s] > 0]  # walk order
-                    winners = hot_ids_np[win_mask_np[s]]  # promotion order
+                    victims = rk.order[np.asarray(_row(vsel_d, s))]  # walk order
+                    winners = hot[np.asarray(_row(win_mask_d, s))[: hot.size]]
                     if victims.size + winners.size < d_demand[s]:
                         raise RuntimeError(
                             "jax sweep: victim supply mismatch (corrupted "
                             "tier state)"
                         )
                     base_n, cand_taken = _resolve_step_victims(
-                        eff_np[victims],
+                        rk.eff[victims],
                         victims,
-                        eff_np[winners],
+                        rk.eff[winners],
                         winners,
                         pools[s]._schedule_events(int(n_cand[s])),
                     )
-                    to_fast = victims[base_n:]
-                    to_slow = winners[cand_taken]
-                    k_pad = _bucket(max(to_fast.size, to_slow.size, 1), 8)
-                    dev_tier = _fix_row(
-                        dev_tier,
-                        int(s),
-                        _pad_i64(to_fast, k_pad, num_pages),
-                        _pad_i64(to_slow, k_pad, num_pages),
-                    )
+                    fix = np.zeros(num_pages, dtype=np.int8)
+                    fix[victims[base_n:]] = 1
+                    fix[winners[cand_taken]] = 2
+                    dev_tier = _fix_row(dev_tier, s, fix)
             # --- commit counters to the host pools (the _try_bulk_step
             # bookkeeping, fed from the pulled schedule)
             for s, pool in enumerate(pools):
@@ -583,8 +582,8 @@ def _sweep_run_jax(
                 if tuned:
                     fm_sizes[s, i] = pool.effective_fm_size
                     t_now[s] += cost.total
-            # --- per-slice tuner steps (simulate() order: post-fold; the
-            # device heat already folded inside the step)
+            _fold_heat(heat, interval_touch, pages)
+            # --- per-slice tuner steps (simulate() order: after the fold)
             if tuned:
                 for s, tuner in enumerate(tuners):
                     te = tune_everys[s]
@@ -610,7 +609,4 @@ def _sweep_run_jax(
                     f"{pool._fast_used}, rss {final_rss[s]} vs "
                     f"{pool._rss_pages})"
                 )
-        heat.value[:] = np.asarray(dev_heat)
-        heat.stamp[:] = n_intervals
-        heat.t = n_intervals
     return times, pools, configs_out, fm_sizes, costs

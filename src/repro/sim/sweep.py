@@ -171,6 +171,44 @@ class TunedSlice:
     tune_every: int | None = None
 
 
+def _hot_sorted(pages: np.ndarray, acc_now: np.ndarray, hot_thr: int) -> np.ndarray:
+    """The interval's promotion candidates, hottest first, stable.
+
+    Touch counts are size-independent, so this order is computed once per
+    interval; each size keeps its slow-tier subset (subsets preserve it).
+    """
+    hot_mask = acc_now >= hot_thr
+    hot = pages[hot_mask]
+    acc_hot = acc_now[hot_mask]
+    if not acc_hot.size:
+        return hot
+    vmax = int(acc_hot.max())
+    if vmax - hot_thr <= 32:
+        # touch counts span a handful of values: a stable counting sort
+        # (hottest first) beats argsort on tens of thousands of
+        # candidates, with the identical tie order
+        order = np.concatenate(
+            [np.flatnonzero(acc_hot == v) for v in range(vmax, hot_thr - 1, -1)]
+        )
+    else:
+        order = np.argsort(-acc_hot, kind="stable")
+    return hot[order]
+
+
+def _fold_heat(heat: LazyHeat, interval_touch: np.ndarray, pages: np.ndarray) -> None:
+    """End the interval: one shared heat fold for all sizes (mirrors
+    ``TieredPagePool.end_interval``'s dense/indexed hybrid) and clear the
+    interval's touch counters."""
+    if pages.size >= interval_touch.size // 8:
+        heat.fold_dense(interval_touch)
+        interval_touch[:] = 0
+    elif pages.size:
+        heat.fold(pages, interval_touch[pages])
+        interval_touch[pages] = 0
+    else:
+        heat.fold(np.empty(0, np.int64), np.empty(0, np.int64))
+
+
 def _sweep_run(
     trace: Trace,
     fm_fracs: np.ndarray,
@@ -365,25 +403,7 @@ def _sweep_run(
         # --- promotion candidates: touch counts are size-independent, so
         # the hottest-first stable order is computed once; each size keeps
         # its slow-tier subset (subsets preserve the stable order)
-        acc_now = interval_touch[pages]
-        hot_mask = acc_now >= policy.hot_thr
-        hot_sorted = pages[hot_mask]
-        acc_hot = acc_now[hot_mask]
-        if acc_hot.size:
-            vmax = int(acc_hot.max())
-            if vmax - policy.hot_thr <= 32:
-                # touch counts span a handful of values: a stable counting
-                # sort (hottest first) beats argsort on tens of thousands
-                # of candidates, with the identical tie order
-                order = np.concatenate(
-                    [
-                        np.flatnonzero(acc_hot == v)
-                        for v in range(vmax, policy.hot_thr - 1, -1)
-                    ]
-                )
-            else:
-                order = np.argsort(-acc_hot, kind="stable")
-            hot_sorted = hot_sorted[order]
+        hot_sorted = _hot_sorted(pages, interval_touch[pages], policy.hot_thr)
         hot_unique = bool(
             hot_sorted.size
             and int(
@@ -465,14 +485,7 @@ def _sweep_run(
                 t_now[s] += cost.total
         # --- one shared heat fold for all sizes (mirrors
         # TieredPagePool.end_interval's dense/indexed hybrid)
-        if pages.size >= num_pages // 8:
-            heat.fold_dense(interval_touch)
-            interval_touch[:] = 0
-        elif pages.size:
-            heat.fold(pages, interval_touch[pages])
-            interval_touch[pages] = 0
-        else:
-            heat.fold(np.empty(0, np.int64), np.empty(0, np.int64))
+        _fold_heat(heat, interval_touch, pages)
         # --- per-slice tuner steps (simulate() order: after end_interval);
         # watermark moves re-partition this slice's stacked tier row from
         # the next interval on — the shared ranking is size-independent
@@ -537,7 +550,8 @@ def _sweep_fm_fracs(
     **Backend selection** (``engine``): ``"numpy"`` — this module's
     stacked-array interval loop, the equivalence oracle; ``"jax"`` — the
     jitted device step of :mod:`repro.sim.jax_engine` (bit-exact by
-    contract, Pallas victim-partition kernel per ``REPRO_PALLAS``).
+    contract, Pallas victim-partition kernel per
+    :func:`repro.kernels.ops.pallas_mode`).
     The JAX backend refuses fault injection, non-``jax_batchable``
     policies, and traces with duplicate page ids per interval; callers
     opt in explicitly (the :mod:`repro.sim.api` planner routes

@@ -58,6 +58,19 @@ def random_trace(seed, rss=4_000, n_intervals=10):
     return tr
 
 
+def _trace_off_jax(seed, parent_pid):
+    """Fan-out trace factory that fails unless it runs in a worker
+    process that has not loaded JAX."""
+    import os
+    import sys
+
+    if os.getpid() == parent_pid:
+        raise RuntimeError("scenario ran in the parent, not a fan-out worker")
+    if "jax" in sys.modules:
+        raise RuntimeError("fan-out worker loaded JAX")
+    return random_trace(seed, n_intervals=3)
+
+
 def pressure_trace(seed, rss=3_000, n_intervals=8):
     """Rotating hot window over most of the RSS: the thrash regime."""
     rng = np.random.default_rng(seed)
@@ -326,23 +339,68 @@ class TestPlannerEquivalence:
             assert_result_equal(a.result, b.result)
 
     def test_start_method_resolution(self):
-        # numpy fan-outs pin the historical fork preference; jax scenarios
-        # flip to spawn (forking an XLA-initialized parent is unsafe)
+        # numpy fan-outs keep the fork preference; a parent that has loaded
+        # JAX spawns instead (forking an XLA-initialized parent is unsafe)
         from repro.sim.api import _resolve_start_method
 
         avail = ["fork", "spawn", "forkserver"]
-        assert _resolve_start_method(None, {"auto"}, avail) == "fork"
-        assert _resolve_start_method(None, {"numpy", "auto"}, avail) == "fork"
-        assert _resolve_start_method(None, {"jax"}, avail) == "spawn"
-        assert _resolve_start_method(None, {"auto", "jax"}, avail) == "spawn"
+        assert _resolve_start_method(None, avail, False) == "fork"
+        assert _resolve_start_method(None, avail, True) == "spawn"
         # an explicit request always wins
-        assert _resolve_start_method("spawn", {"auto"}, avail) == "spawn"
-        assert _resolve_start_method("fork", {"jax"}, avail) == "fork"
-        # degraded platforms: fall back to the platform default / spawn
-        assert _resolve_start_method(None, {"auto"}, ["spawn"]) is None
-        assert _resolve_start_method(None, {"jax"}, ["fork"]) is None
+        assert _resolve_start_method("spawn", avail, False) == "spawn"
+        assert _resolve_start_method("fork", avail, True) == "fork"
+        # degraded platforms: fall back to the platform default
+        assert _resolve_start_method(None, ["spawn"], False) is None
+        assert _resolve_start_method(None, ["fork"], True) is None
         with pytest.raises(ValueError, match="not available"):
-            _resolve_start_method("forkserver", {"auto"}, ["fork", "spawn"])
+            _resolve_start_method("forkserver", ["fork", "spawn"], False)
+
+    def test_jax_engine_runs_in_calling_process(self, monkeypatch):
+        # the accelerator belongs to one process: engine="jax" scenarios
+        # never reach the process fan-out, whatever the parallelism
+        import repro.sim.api as api
+
+        def no_fanout(*a, **k):
+            raise AssertionError("engine='jax' scenario was fanned out")
+
+        monkeypatch.setattr(api, "_fanout", no_fanout)
+        traces = [pressure_trace(s, rss=1_000, n_intervals=3) for s in (1, 2)]
+
+        def exp(engine):
+            return Experiment(
+                scenarios=[
+                    Scenario(trace=tr, name=f"s{i}", engine=engine)
+                    for i, tr in enumerate(traces)
+                ],
+                fm_fracs=(0.5,),
+            )
+
+        jx = run(exp("jax"), parallelism=2)
+        base = run(exp("numpy"), parallelism=1)
+        assert jx.backends == ("jax_sweep",)
+        for a, b in zip(jx.runs, base.runs):
+            assert_result_equal(a.result, b.result)
+
+    def test_numpy_fanout_after_jax_keeps_workers_off_jax(self):
+        # a parent that has run JAX fans numpy scenarios out to workers
+        # that never load JAX, and the fan-out finishes (no fork hang)
+        import os
+
+        import jax.numpy as jnp
+
+        jnp.zeros(()).block_until_ready()
+        exp = Experiment(
+            scenarios=[
+                Scenario(
+                    trace=functools.partial(_trace_off_jax, s, os.getpid()),
+                    name=f"w{s}",
+                )
+                for s in (3, 4)
+            ],
+            fm_fracs=(0.5,),
+        )
+        rs = run(exp, parallelism=2, scenario_timeout=300)
+        assert len(rs.runs) == 2
 
     def test_fanout_spawn_matches_serial(self):
         # the spawn context re-imports repro in each worker; results must

@@ -692,7 +692,7 @@ class TestJaxSweepEquivalence:
     @pytest.fixture(autouse=True)
     def _interpret_mode(self, monkeypatch):
         # force the Pallas kernel through interpreter mode so these tests
-        # cover the kernel code path on CPU, not just the jnp fallback
+        # cover the kernel code path on CPU, not just the jnp reference
         monkeypatch.setenv("REPRO_PALLAS", "interpret")
 
     def _assert_three_lanes(self, tr, fracs, cap=None, kswapd=None,
@@ -883,7 +883,7 @@ class TestJaxEngineRouting:
 class TestVictimPartitionKernel:
     """The Pallas segment-scan re-partition == a per-row heap replay of
     the demotion walk, property-tested over random fast-tier layouts and
-    demands (and always equal to the jnp fallback, so mode selection can
+    demands (and always equal to the jnp reference, so mode selection can
     never perturb victim identities)."""
 
     @pytest.fixture(autouse=True)

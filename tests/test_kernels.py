@@ -167,8 +167,8 @@ class TestPageMigrate:
 class TestStridedProbe:
     @pytest.mark.parametrize("ai_iters", [0, 1, 7, 32])
     def test_matches_ref(self, ai_iters):
-        fp = jnp.asarray(RNG.normal(size=(10, 128)), jnp.float32)
-        sp = jnp.asarray(RNG.normal(size=(12, 128)), jnp.float32)
+        fp = jnp.asarray(RNG.normal(size=(10, 8, 128)), jnp.float32)
+        sp = jnp.asarray(RNG.normal(size=(12, 8, 128)), jnp.float32)
         fi = jnp.asarray([0, 3, 5, 9], jnp.int32)
         si = jnp.asarray([1, 2, 11], jnp.int32)
         r = ref.strided_probe(fp, sp, fi, si, ai_iters)
@@ -179,8 +179,8 @@ class TestStridedProbe:
     def test_ai_knob_changes_flops_not_reads(self):
         """Arithmetic intensity knob is pure compute: output is a
         deterministic function; more iterations = more FMAs applied."""
-        fp = jnp.ones((4, 64), jnp.float32)
-        sp = jnp.ones((4, 64), jnp.float32)
+        fp = jnp.ones((4, 8, 128), jnp.float32)
+        sp = jnp.ones((4, 8, 128), jnp.float32)
         fi = jnp.asarray([0, 1], jnp.int32)
         si = jnp.asarray([2], jnp.int32)
         o1 = strided_probe(fp, sp, fi, si, 1, interpret=True)

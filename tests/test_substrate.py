@@ -198,7 +198,7 @@ class TestTieredServing:
         kv.ensure_resident(np.array([5]))
         got = kv.hbm[int(kv.hbm_slot[5])]
         np.testing.assert_array_equal(
-            np.asarray(got, np.float32), np.asarray(data, np.float32)
+            np.asarray(got, np.float32).reshape(-1), np.asarray(data, np.float32)
         )
 
     def test_server_rounds_and_watermark(self):
@@ -210,3 +210,18 @@ class TestTieredServing:
         assert s["migrated_in"] > 0
         # HBM occupancy respects the watermark-set budget
         assert kv.pool.fast_used <= 64
+
+
+class TestCompileCachePath:
+    def test_env_wins_else_fixed_checkout_path(self, monkeypatch, tmp_path):
+        from repro.runtime.compile_cache import ENV, compile_cache_dir
+
+        monkeypatch.setenv(ENV, str(tmp_path / "from_env"))
+        assert compile_cache_dir(tmp_path) == (str(tmp_path / "from_env"), True)
+        monkeypatch.delenv(ENV)
+        path, from_env = compile_cache_dir(tmp_path)
+        assert not from_env
+        assert path == str(tmp_path.resolve() / ".jax_cache")
+        # the same checkout always maps to the same directory: the path is
+        # part of the cache key, so it must never vary between runs
+        assert compile_cache_dir(tmp_path) == (path, False)
