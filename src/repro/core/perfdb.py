@@ -23,6 +23,7 @@ from pathlib import Path
 import numpy as np
 
 from repro.core.telemetry import ConfigVector
+from repro.runtime import tracing
 
 
 class PerfDBUnavailable(RuntimeError):
@@ -204,6 +205,7 @@ class PerfDB:
         self.records.append(record)
         self._index = None  # invalidate
 
+    @tracing.traced("perfdb.index")
     def build(self) -> None:
         if not self.records:
             raise ValueError("empty performance database")

@@ -30,6 +30,7 @@ from repro.core.perfdb import PerfDB, PerfDBUnavailable, PerfRecord
 from repro.core.telemetry import ConfigVector
 from repro.core.trace import Trace
 from repro.core.watermark import WatermarkController
+from repro.runtime import tracing
 
 
 @dataclass
@@ -302,6 +303,7 @@ def _microbench_trace(
     )
 
 
+@tracing.traced("perfdb.build")
 def build_database(
     configs: Iterable[ConfigVector],
     run_microbench: Callable[[Trace, float], float] | None = None,

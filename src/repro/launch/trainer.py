@@ -16,7 +16,7 @@ from repro.checkpoint import CheckpointManager
 from repro.data import SyntheticLMDataset
 from repro.launch.train import make_train_fns, width_scaled_lr
 from repro.models.config import ModelConfig
-from repro.runtime import StepWatchdog, StragglerMonitor, retry_step
+from repro.runtime.fault_tolerance import StepWatchdog, StragglerMonitor, retry_step
 
 
 @dataclass

@@ -1,9 +1,1 @@
-from repro.runtime.fault_tolerance import StepWatchdog, retry_step, StragglerMonitor
-from repro.runtime.elastic import ElasticMeshManager
-
-__all__ = [
-    "StepWatchdog",
-    "retry_step",
-    "StragglerMonitor",
-    "ElasticMeshManager",
-]
+"""Runtime services: fault tolerance, elastic meshes, compile cache, tracing."""

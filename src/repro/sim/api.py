@@ -200,6 +200,7 @@ from repro.core.telemetry import ConfigVector
 from repro.core.trace import Trace
 from repro.core.tuner import TunaTuner, TunerConfig, TunerDecision
 from repro.core.watermark import WatermarkController, WatermarkEvent
+from repro.runtime import tracing
 from repro.sim.costmodel import HardwareProfile, IntervalCosts, OPTANE_LIKE
 from repro.sim.engine import SimResult, _simulate
 from repro.sim.faults import FaultInjector, FaultSpec
@@ -673,6 +674,7 @@ def _decision_from_dict(d: dict) -> TunerDecision:
 # ----------------------------------------------------------------- planner
 
 
+@tracing.traced("scenario.trace")
 def _resolve_trace(scenario: Scenario) -> Trace | None:
     tr = scenario.trace
     if tr is None or isinstance(tr, Trace):
@@ -710,6 +712,7 @@ def _effective_fm(cap: int, frac: float) -> int:
     return int(max(1, min(cap, int(round(frac * cap)))))
 
 
+@tracing.traced("scenario")
 def _run_scenario(
     scenario: Scenario,
     fm_fracs: tuple,
@@ -1271,6 +1274,7 @@ def _cache_path(cache_dir, name: str, spec: dict) -> Path:
     return Path(cache_dir) / f"runset_{safe}_{digest}.json"
 
 
+@tracing.traced("experiment")
 def run(
     experiment: Experiment,
     db=None,

@@ -70,6 +70,7 @@ from repro.kernels.demote_rank import (
     _victim_partition_pallas,
 )
 from repro.kernels.ops import pallas_mode
+from repro.runtime import tracing
 from repro.sim.costmodel import absorb_cache, effective_mlp, interval_time
 from repro.sim.sweep import _fold_heat, _hot_sorted
 from repro.tiering.page_pool import (
@@ -327,6 +328,7 @@ def _tie_groups(g: GlobalDemoteRank) -> np.ndarray:
     return grp
 
 
+@tracing.traced("sweep")
 def _sweep_run_jax(
     trace,
     fm_fracs: np.ndarray,
@@ -344,8 +346,13 @@ def _sweep_run_jax(
 
     Same signature, same ``(times, pools, configs_out, fm_sizes, costs)``
     return, bit-exact results; see the module docstring for the contract.
+    The layers are :mod:`repro.runtime.tracing` spans (``sweep.*``,
+    ``interval.*``, ``fixup.*``) with counters of intervals, interfering
+    sizes, merged reclaim events, device dispatches and host<->device
+    bytes; they time and count, and feed nothing back.
     """
-    _require_jax_runnable(trace, policy, faults)
+    with tracing.span("sweep.eligibility"):
+        _require_jax_runnable(trace, policy, faults)
     n_sizes = int(np.asarray(fm_fracs).size)
     num_pages = int(trace.rss_pages)
     cap = int(hw_capacity_pages or trace.rss_pages)
@@ -355,258 +362,310 @@ def _sweep_run_jax(
     commit_step = _build_commit_step(pallas_mode())
 
     with jax.enable_x64(True):
-        # host-side slice pools: watermarks, stats, rss — the control
-        # plane the profilers/tuners read — plus the shared heat and
-        # touch counters. Tier rows live on device for the run and are
-        # imported back at the end.
-        tier_b = np.full((n_sizes, num_pages), int(Tier.UNALLOCATED), np.int8)
-        halflife_decay = 0.5 ** (1.0 / 2.0)
-        heat = LazyHeat(num_pages, halflife_decay)
-        interval_acc = np.zeros(num_pages, dtype=np.int64)
-        interval_touch = np.zeros(num_pages, dtype=np.int64)
-        pools = []
-        for s in range(n_sizes):
-            pool = TieredPagePool._shared_slice(
-                tier_row=tier_b[s],
-                heat=heat,
-                interval_acc=interval_acc,
-                interval_touch=interval_touch,
-                hw_capacity=cap,
-                page_bytes=hw.page_bytes,
-                kswapd_batch=kswapd_batch,
-                seed=seed,
-            )
-            pool.set_fm_size(int(round(float(fm_fracs[s]) * cap)))
-            if trace.slow_pages is not None:
-                pool.place(trace.slow_pages, Tier.SLOW)
-            pools.append(pool)
-        tuned = tuners is not None
-        if tuned:
-            for pool, tuner in zip(pools, tuners):
-                if tuner is not None:
-                    tuner.bind_pool(pool, cap)
+        with tracing.span("sweep.setup"):
+            # host-side slice pools: watermarks, stats, rss — the control
+            # plane the profilers/tuners read — plus the shared heat and
+            # touch counters. Tier rows live on device for the run and are
+            # imported back at the end.
+            tier_b = np.full((n_sizes, num_pages), int(Tier.UNALLOCATED), np.int8)
+            halflife_decay = 0.5 ** (1.0 / 2.0)
+            heat = LazyHeat(num_pages, halflife_decay)
+            interval_acc = np.zeros(num_pages, dtype=np.int64)
+            interval_touch = np.zeros(num_pages, dtype=np.int64)
+            pools = []
+            for s in range(n_sizes):
+                pool = TieredPagePool._shared_slice(
+                    tier_row=tier_b[s],
+                    heat=heat,
+                    interval_acc=interval_acc,
+                    interval_touch=interval_touch,
+                    hw_capacity=cap,
+                    page_bytes=hw.page_bytes,
+                    kswapd_batch=kswapd_batch,
+                    seed=seed,
+                )
+                pool.set_fm_size(int(round(float(fm_fracs[s]) * cap)))
+                if trace.slow_pages is not None:
+                    pool.place(trace.slow_pages, Tier.SLOW)
+                pools.append(pool)
+            tuned = tuners is not None
+            if tuned:
+                for pool, tuner in zip(pools, tuners):
+                    if tuner is not None:
+                        tuner.bind_pool(pool, cap)
 
-        dev_tier = jnp.asarray(TieredPagePool._export_tier_stack(pools))
-        allocated = tier_b[0] != int(Tier.UNALLOCATED)
-        # device constants, made on first use: "no new page" allocation
-        # ranks, and the identity ranking of intervals that only promote
-        no_new = identity = None
+            tier_stack = TieredPagePool._export_tier_stack(pools)
+            tracing.count("xfer.h2d_bytes", tier_stack.nbytes)
+            dev_tier = jnp.asarray(tier_stack)
+            allocated = tier_b[0] != int(Tier.UNALLOCATED)
+            # device constants, made on first use: "no new page" allocation
+            # ranks, and the identity ranking of intervals that only promote
+            no_new = identity = None
 
-        n_intervals = len(trace)
-        times = np.zeros((n_sizes, n_intervals), dtype=np.float64)
-        profilers = configs_out = None
-        if collect_configs:
-            from repro.core.telemetry import IntervalProfiler
+            n_intervals = len(trace)
+            times = np.zeros((n_sizes, n_intervals), dtype=np.float64)
+            profilers = configs_out = None
+            if collect_configs:
+                from repro.core.telemetry import IntervalProfiler
 
-            profilers = [
-                IntervalProfiler(hot_thr=hot_thr, num_threads=trace.num_threads)
-                for _ in range(n_sizes)
-            ]
-            configs_out = [[] for _ in range(n_sizes)]
-        costs = [[] for _ in range(n_sizes)]
-        fm_sizes = t_now = None
-        if tuned:
-            fm_sizes = np.zeros((n_sizes, n_intervals), dtype=np.int64)
-            t_now = [0.0] * n_sizes
+                profilers = [
+                    IntervalProfiler(hot_thr=hot_thr, num_threads=trace.num_threads)
+                    for _ in range(n_sizes)
+                ]
+                configs_out = [[] for _ in range(n_sizes)]
+            costs = [[] for _ in range(n_sizes)]
+            fm_sizes = t_now = None
+            if tuned:
+                fm_sizes = np.zeros((n_sizes, n_intervals), dtype=np.int64)
+                t_now = [0.0] * n_sizes
 
         for i, ia in enumerate(trace):
-            pages = np.asarray(ia.pages, dtype=np.int64)
-            counts_mem = absorb_cache(ia.counts, hw.llc_pages)
-            mlp_eff = effective_mlp(counts_mem, hw.mlp, trace.num_threads)
-            touches = np.asarray(ia.touches, dtype=np.int64)
-            rep = np.minimum(touches, hot_thr)
-            # --- host allocation bookkeeping (pre-step, per size): the
-            # new-page set and rss delta are size-independent, the
-            # fast-prefix length is each size's watermark budget
-            new_mask = ~allocated[pages] if pages.size else np.zeros(0, bool)
-            n_new = int(np.count_nonzero(new_mask))
-            n_fast_arr = np.zeros(n_sizes, dtype=np.int32)
-            if n_new:
-                for s, pool in enumerate(pools):
-                    budget = max(0, pool.fast_free - pool.watermarks.low_free)
-                    nf = min(budget, n_new)
-                    n_fast_arr[s] = nf
-                    pool.stats.alloc_fast += int(nf)
-                    pool.stats.alloc_slow += int(n_new - nf)
-                    pool._rss_pages += n_new
-                    pool._fast_used += int(nf)
-                allocated[pages[new_mask]] = True
-                new_rank = np.full(num_pages, -1, dtype=np.int32)
-                new_rank[pages[new_mask]] = np.arange(n_new, dtype=np.int32)
-            else:
-                if no_new is None:
-                    no_new = jnp.full(num_pages, -1, dtype=jnp.int32)
-                new_rank = no_new
-            # --- size-independent host work: the interval's touches,
-            # hottest-first candidates and their admission test
-            interval_touch[pages] += touches  # ids are unique per interval
-            hot = _hot_sorted(pages, touches, hot_thr)
-            if admit_margin is None:
-                hot_ok = np.ones(hot.size, dtype=bool)
-            else:
-                # AdmissionTPPPolicy._admit: trace-pure, size-independent
-                eff_hot = heat.lookahead(hot) + interval_touch[hot]
-                hot_ok = eff_hot >= float(admit_margin) * hot_thr
-            # --- schedule inputs: post-allocation free/fast state
-            free_a = np.empty(n_sizes, dtype=np.int64)
-            fastc_a = np.empty(n_sizes, dtype=np.int64)
-            minf_a = np.empty(n_sizes, dtype=np.int64)
-            lowf_a = np.empty(n_sizes, dtype=np.int64)
-            highf_a = np.empty(n_sizes, dtype=np.int64)
-            kswapd_a = np.empty(n_sizes, dtype=np.int64)
-            for s, pool in enumerate(pools):
-                wm = pool.watermarks
-                free_a[s] = pool.fast_free
-                fastc_a[s] = pool.fast_used
-                minf_a[s] = wm.min_free
-                lowf_a[s] = wm.low_free
-                highf_a[s] = wm.high_free
-                kswapd_a[s] = pool.kswapd_batch
-            p_pad = _bucket(pages.size)
-            tier_alloc, sums_d, counters_d, win_mask_d = schedule_step(
-                dev_tier,
-                new_rank,
-                n_fast_arr,
-                _pad(pages, p_pad, 0, np.int32),
-                _pad(counts_mem, p_pad, 0, np.int64),
-                _pad(rep, p_pad, 0, np.int64),
-                _pad(np.ones(pages.size, bool), p_pad, False, bool),
-                _pad(hot, p_pad, 0, np.int32),
-                _pad(np.ones(hot.size, bool), p_pad, False, bool),
-                _pad(hot_ok, p_pad, False, bool),
-                free_a, fastc_a, minf_a, lowf_a, highf_a, kswapd_a,
-            )
-            counters = np.asarray(counters_d)
-            (pm_pr, pm_de, pm_fail, direct_total, events, d_demand,
-             rejected, n_cand) = counters
-            dev_tier = tier_alloc
-            if pm_pr.any() or d_demand.any():
-                # --- the shared demotion ranking, only when some size
-                # demotes (promote-only intervals commit in page order)
-                if d_demand.any():
-                    rk = GlobalDemoteRank(heat.lookahead_dense() + interval_touch)
-                    grp_np = _tie_groups(rk)
-                    order = jnp.asarray(rk.order.astype(np.int32))
-                    rank_inv = jnp.asarray(rk.rank.astype(np.int32))
-                    grp = jnp.asarray(grp_np)
-                    hot_rank = rk.rank[hot]
-                    hot_grp = _pad(grp_np[hot_rank], p_pad, _NO_GROUP, np.int32)
-                else:
-                    rk = None
-                    if identity is None:
-                        identity = jnp.arange(num_pages, dtype=jnp.int32)
-                    order = rank_inv = grp = identity
-                    hot_rank = hot
-                    hot_grp = np.full(p_pad, _NO_GROUP, dtype=np.int32)
-                hot_slot = np.full(num_pages, p_pad, dtype=np.int32)
-                hot_slot[hot_rank] = np.arange(hot.size, dtype=np.int32)
-                dev_tier, interf_d, vsel_d = commit_step(
-                    tier_alloc, order, rank_inv, grp, hot_slot, win_mask_d,
-                    hot_grp, counters_d,
-                )
-                interf = np.asarray(interf_d)
-                # --- thrash regime: resolve interfering sizes' victim
-                # identities with the numpy sweep's own host resolver and
-                # patch the device tier (counters are schedule-determined
-                # and already exact)
-                for s in np.flatnonzero(interf):
-                    victims = rk.order[np.asarray(_row(vsel_d, s))]  # walk order
-                    winners = hot[np.asarray(_row(win_mask_d, s))[: hot.size]]
-                    if victims.size + winners.size < d_demand[s]:
-                        raise RuntimeError(
-                            "jax sweep: victim supply mismatch (corrupted "
-                            "tier state)"
+            with tracing.span("sweep.interval", interval=i):
+                tracing.count("sweep.intervals")
+                with tracing.span("interval.prep"):
+                    pages = np.asarray(ia.pages, dtype=np.int64)
+                    counts_mem = absorb_cache(ia.counts, hw.llc_pages)
+                    mlp_eff = effective_mlp(counts_mem, hw.mlp, trace.num_threads)
+                    touches = np.asarray(ia.touches, dtype=np.int64)
+                    rep = np.minimum(touches, hot_thr)
+                    # --- host allocation bookkeeping (pre-step, per size):
+                    # the new-page set and rss delta are size-independent,
+                    # the fast-prefix length is each size's watermark budget
+                    new_mask = ~allocated[pages] if pages.size else np.zeros(0, bool)
+                    n_new = int(np.count_nonzero(new_mask))
+                    n_fast_arr = np.zeros(n_sizes, dtype=np.int32)
+                    if n_new:
+                        for s, pool in enumerate(pools):
+                            budget = max(0, pool.fast_free - pool.watermarks.low_free)
+                            nf = min(budget, n_new)
+                            n_fast_arr[s] = nf
+                            pool.stats.alloc_fast += int(nf)
+                            pool.stats.alloc_slow += int(n_new - nf)
+                            pool._rss_pages += n_new
+                            pool._fast_used += int(nf)
+                        allocated[pages[new_mask]] = True
+                        new_rank = np.full(num_pages, -1, dtype=np.int32)
+                        new_rank[pages[new_mask]] = np.arange(n_new, dtype=np.int32)
+                    else:
+                        if no_new is None:
+                            no_new = jnp.full(num_pages, -1, dtype=jnp.int32)
+                        new_rank = no_new
+                    # --- size-independent host work: the interval's
+                    # touches, hottest-first candidates and their admission
+                    interval_touch[pages] += touches  # ids are unique per interval
+                    hot = _hot_sorted(pages, touches, hot_thr)
+                    if admit_margin is None:
+                        hot_ok = np.ones(hot.size, dtype=bool)
+                    else:
+                        # AdmissionTPPPolicy._admit: trace-pure, size-independent
+                        eff_hot = heat.lookahead(hot) + interval_touch[hot]
+                        hot_ok = eff_hot >= float(admit_margin) * hot_thr
+                    # --- schedule inputs: post-allocation free/fast state
+                    free_a = np.empty(n_sizes, dtype=np.int64)
+                    fastc_a = np.empty(n_sizes, dtype=np.int64)
+                    minf_a = np.empty(n_sizes, dtype=np.int64)
+                    lowf_a = np.empty(n_sizes, dtype=np.int64)
+                    highf_a = np.empty(n_sizes, dtype=np.int64)
+                    kswapd_a = np.empty(n_sizes, dtype=np.int64)
+                    for s, pool in enumerate(pools):
+                        wm = pool.watermarks
+                        free_a[s] = pool.fast_free
+                        fastc_a[s] = pool.fast_used
+                        minf_a[s] = wm.min_free
+                        lowf_a[s] = wm.low_free
+                        highf_a[s] = wm.high_free
+                        kswapd_a[s] = pool.kswapd_batch
+                    p_pad = _bucket(pages.size)
+                    step_in = (
+                        new_rank,
+                        n_fast_arr,
+                        _pad(pages, p_pad, 0, np.int32),
+                        _pad(counts_mem, p_pad, 0, np.int64),
+                        _pad(rep, p_pad, 0, np.int64),
+                        _pad(np.ones(pages.size, bool), p_pad, False, bool),
+                        _pad(hot, p_pad, 0, np.int32),
+                        _pad(np.ones(hot.size, bool), p_pad, False, bool),
+                        _pad(hot_ok, p_pad, False, bool),
+                        free_a, fastc_a, minf_a, lowf_a, highf_a, kswapd_a,
+                    )
+                if tracing.active():  # new_rank may be the device constant
+                    tracing.count("xfer.h2d_bytes",
+                                  sum(a.nbytes for a in step_in if isinstance(a, np.ndarray)))
+                tracing.count("device.dispatches")
+                with tracing.span("interval.schedule"):
+                    tier_alloc, sums_d, counters_d, win_mask_d = schedule_step(
+                        dev_tier, *step_in
+                    )
+                with tracing.span("interval.pull"):
+                    counters = np.asarray(counters_d)
+                if tracing.active():
+                    tracing.count("xfer.d2h_bytes", counters.nbytes)
+                (pm_pr, pm_de, pm_fail, direct_total, events, d_demand,
+                 rejected, n_cand) = counters
+                dev_tier = tier_alloc
+                if pm_pr.any() or d_demand.any():
+                    with tracing.span("interval.rank"):
+                        # --- the shared demotion ranking, only when some
+                        # size demotes (promote-only intervals commit in
+                        # page order)
+                        if d_demand.any():
+                            rk = GlobalDemoteRank(heat.lookahead_dense() + interval_touch)
+                            grp_np = _tie_groups(rk)
+                            order = jnp.asarray(rk.order.astype(np.int32))
+                            rank_inv = jnp.asarray(rk.rank.astype(np.int32))
+                            grp = jnp.asarray(grp_np)
+                            if tracing.active():
+                                tracing.count("xfer.h2d_bytes",
+                                              order.nbytes + rank_inv.nbytes + grp.nbytes)
+                            hot_rank = rk.rank[hot]
+                            hot_grp = _pad(grp_np[hot_rank], p_pad, _NO_GROUP, np.int32)
+                        else:
+                            rk = None
+                            if identity is None:
+                                identity = jnp.arange(num_pages, dtype=jnp.int32)
+                            order = rank_inv = grp = identity
+                            hot_rank = hot
+                            hot_grp = np.full(p_pad, _NO_GROUP, dtype=np.int32)
+                        hot_slot = np.full(num_pages, p_pad, dtype=np.int32)
+                        hot_slot[hot_rank] = np.arange(hot.size, dtype=np.int32)
+                    if tracing.active():
+                        tracing.count("xfer.h2d_bytes", hot_slot.nbytes + hot_grp.nbytes)
+                    tracing.count("device.dispatches")
+                    with tracing.span("interval.commit"):
+                        dev_tier, interf_d, vsel_d = commit_step(
+                            tier_alloc, order, rank_inv, grp, hot_slot, win_mask_d,
+                            hot_grp, counters_d,
                         )
-                    base_n, cand_taken = _resolve_step_victims(
-                        rk.eff[victims],
-                        victims,
-                        rk.eff[winners],
-                        winners,
-                        pools[s]._schedule_events(int(n_cand[s])),
-                    )
-                    fix = np.zeros(num_pages, dtype=np.int8)
-                    fix[victims[base_n:]] = 1
-                    fix[winners[cand_taken]] = 2
-                    dev_tier = _fix_row(dev_tier, s, fix)
-            # --- commit counters to the host pools (the _try_bulk_step
-            # bookkeeping, fed from the pulled schedule)
-            for s, pool in enumerate(pools):
-                pool._fast_used += int(pm_pr[s]) - int(d_demand[s])
-                st = pool.stats
-                st.pgdemote_direct += int(direct_total[s])
-                st.pgdemote_kswapd += int(pm_de[s]) - int(direct_total[s])
-                st.direct_reclaim_events += int(events[s])
-                st.pgpromote_success += int(pm_pr[s])
-            # --- per-size telemetry + cost (host, identical arithmetic)
-            sums = np.asarray(sums_d)
-            pacc_f_all = sums[:, 0]
-            pacc_s_all = int(counts_mem.sum()) - pacc_f_all
-            ptouch_f_all = sums[:, 1]
-            ptouch_s_all = int(rep.sum()) - ptouch_f_all
-            warm_pages_all = sums[:, 2]
-            warm_touch_all = sums[:, 3]
-            for s, pool in enumerate(pools):
-                outcome = PolicyOutcome(
-                    pm_pr=int(pm_pr[s]),
-                    pm_de=int(pm_de[s]),
-                    pm_fail=int(pm_fail[s]),
-                    direct_reclaim=int(direct_total[s]),
-                    pm_admit_fail=int(rejected[s]),
-                )
-                if profilers is not None:
-                    profilers[s].record_accesses(
-                        int(ptouch_f_all[s]),
-                        int(ptouch_s_all[s]),
-                        ia.ops,
-                        cachelines=int(pacc_f_all[s]) + int(pacc_s_all[s]),
-                        warm_pages=int(warm_pages_all[s]),
-                        warm_touches=int(warm_touch_all[s]),
-                    )
-                    profilers[s].record_policy(outcome)
-                    configs_out[s].append(profilers[s].finish(pool))
-                cost = interval_time(
-                    hw,
-                    pacc_f=int(pacc_f_all[s]),
-                    pacc_s=int(pacc_s_all[s]),
-                    ops=ia.ops,
-                    pm_pr=outcome.pm_pr,
-                    pm_de=outcome.pm_de,
-                    pm_fail=outcome.pm_fail,
-                    direct_reclaimed=int(direct_total[s]),
-                    mlp_eff=mlp_eff,
-                    num_threads=trace.num_threads,
-                    rand_frac=ia.rand_frac,
-                )
-                times[s, i] = cost.total
-                costs[s].append(cost)
+                    with tracing.span("interval.pull"):
+                        interf = np.asarray(interf_d)
+                    if tracing.active():
+                        tracing.count("xfer.d2h_bytes", interf.nbytes)
+                    # --- thrash regime: resolve interfering sizes' victim
+                    # identities with the numpy sweep's own host resolver
+                    # and patch the device tier (counters are
+                    # schedule-determined and already exact)
+                    with tracing.span("interval.fixup"):
+                        for s in np.flatnonzero(interf):
+                            tracing.count("sweep.interfering_sizes")
+                            tracing.count("device.dispatches", 3)  # 2 x _row, _fix_row
+                            with tracing.span("fixup.pull", size=s):
+                                vsel = np.asarray(_row(vsel_d, s))
+                                win = np.asarray(_row(win_mask_d, s))
+                            if tracing.active():
+                                tracing.count("xfer.d2h_bytes", vsel.nbytes + win.nbytes)
+                            victims = rk.order[vsel]  # walk order
+                            winners = hot[win[: hot.size]]
+                            if victims.size + winners.size < d_demand[s]:
+                                raise RuntimeError(
+                                    "jax sweep: victim supply mismatch (corrupted "
+                                    "tier state)"
+                                )
+                            with tracing.span("fixup.merge", size=s):
+                                step_events = pools[s]._schedule_events(int(n_cand[s]))
+                                base_n, cand_taken = _resolve_step_victims(
+                                    rk.eff[victims],
+                                    victims,
+                                    rk.eff[winners],
+                                    winners,
+                                    step_events,
+                                )
+                            with tracing.span("fixup.patch", size=s):
+                                fix = np.zeros(num_pages, dtype=np.int8)
+                                fix[victims[base_n:]] = 1
+                                fix[winners[cand_taken]] = 2
+                                dev_tier = _fix_row(dev_tier, s, fix)
+                            if tracing.active():
+                                tracing.count("xfer.h2d_bytes", fix.nbytes)
+                with tracing.span("interval.account"):
+                    # --- commit counters to the host pools (the
+                    # _try_bulk_step bookkeeping, fed from the pulled
+                    # schedule)
+                    for s, pool in enumerate(pools):
+                        pool._fast_used += int(pm_pr[s]) - int(d_demand[s])
+                        st = pool.stats
+                        st.pgdemote_direct += int(direct_total[s])
+                        st.pgdemote_kswapd += int(pm_de[s]) - int(direct_total[s])
+                        st.direct_reclaim_events += int(events[s])
+                        st.pgpromote_success += int(pm_pr[s])
+                    # --- per-size telemetry + cost (host, identical
+                    # arithmetic)
+                    with tracing.span("interval.pull"):
+                        sums = np.asarray(sums_d)
+                    if tracing.active():
+                        tracing.count("xfer.d2h_bytes", sums.nbytes)
+                    pacc_f_all = sums[:, 0]
+                    pacc_s_all = int(counts_mem.sum()) - pacc_f_all
+                    ptouch_f_all = sums[:, 1]
+                    ptouch_s_all = int(rep.sum()) - ptouch_f_all
+                    warm_pages_all = sums[:, 2]
+                    warm_touch_all = sums[:, 3]
+                    for s, pool in enumerate(pools):
+                        outcome = PolicyOutcome(
+                            pm_pr=int(pm_pr[s]),
+                            pm_de=int(pm_de[s]),
+                            pm_fail=int(pm_fail[s]),
+                            direct_reclaim=int(direct_total[s]),
+                            pm_admit_fail=int(rejected[s]),
+                        )
+                        if profilers is not None:
+                            profilers[s].record_accesses(
+                                int(ptouch_f_all[s]),
+                                int(ptouch_s_all[s]),
+                                ia.ops,
+                                cachelines=int(pacc_f_all[s]) + int(pacc_s_all[s]),
+                                warm_pages=int(warm_pages_all[s]),
+                                warm_touches=int(warm_touch_all[s]),
+                            )
+                            profilers[s].record_policy(outcome)
+                            configs_out[s].append(profilers[s].finish(pool))
+                        cost = interval_time(
+                            hw,
+                            pacc_f=int(pacc_f_all[s]),
+                            pacc_s=int(pacc_s_all[s]),
+                            ops=ia.ops,
+                            pm_pr=outcome.pm_pr,
+                            pm_de=outcome.pm_de,
+                            pm_fail=outcome.pm_fail,
+                            direct_reclaimed=int(direct_total[s]),
+                            mlp_eff=mlp_eff,
+                            num_threads=trace.num_threads,
+                            rand_frac=ia.rand_frac,
+                        )
+                        times[s, i] = cost.total
+                        costs[s].append(cost)
+                        if tuned:
+                            fm_sizes[s, i] = pool.effective_fm_size
+                            t_now[s] += cost.total
+                with tracing.span("interval.fold"):
+                    _fold_heat(heat, interval_touch, pages)
+                # --- per-slice tuner steps (simulate() order: after the fold)
                 if tuned:
-                    fm_sizes[s, i] = pool.effective_fm_size
-                    t_now[s] += cost.total
-            _fold_heat(heat, interval_touch, pages)
-            # --- per-slice tuner steps (simulate() order: after the fold)
-            if tuned:
-                for s, tuner in enumerate(tuners):
-                    te = tune_everys[s]
-                    if tuner is not None and te and (i + 1) % te == 0:
-                        window = costs[s][-te:]
-                        acc = sum(
-                            c.pacc_f + c.pacc_s for c in configs_out[s][-te:]
-                        )
-                        tpa = sum(c.total for c in window) / max(acc, 1)
-                        tuner.step(
-                            configs_out[s][-1], t=t_now[s], measured_tpa=tpa
-                        )
+                    with tracing.span("interval.tune"):
+                        for s, tuner in enumerate(tuners):
+                            te = tune_everys[s]
+                            if tuner is not None and te and (i + 1) % te == 0:
+                                window = costs[s][-te:]
+                                acc = sum(
+                                    c.pacc_f + c.pacc_s for c in configs_out[s][-te:]
+                                )
+                                tpa = sum(c.total for c in window) / max(acc, 1)
+                                tuner.step(
+                                    configs_out[s][-1], t=t_now[s], measured_tpa=tpa
+                                )
         # --- import the final device state back into the host pools so
         # they are indistinguishable from a numpy-sweep run's
-        final_fast = [pool._fast_used for pool in pools]
-        final_rss = [pool._rss_pages for pool in pools]
-        TieredPagePool._import_tier_stack(pools, np.asarray(dev_tier))
-        for s, pool in enumerate(pools):
-            if pool._fast_used != final_fast[s] or pool._rss_pages != final_rss[s]:
-                raise RuntimeError(
-                    "jax sweep: host/device tier accounting diverged "
-                    f"(size {s}: fast_used {final_fast[s]} vs "
-                    f"{pool._fast_used}, rss {final_rss[s]} vs "
-                    f"{pool._rss_pages})"
-                )
+        with tracing.span("sweep.import"):
+            final_fast = [pool._fast_used for pool in pools]
+            final_rss = [pool._rss_pages for pool in pools]
+            final_tier = np.asarray(dev_tier)
+            tracing.count("xfer.d2h_bytes", final_tier.nbytes)
+            TieredPagePool._import_tier_stack(pools, final_tier)
+            for s, pool in enumerate(pools):
+                if pool._fast_used != final_fast[s] or pool._rss_pages != final_rss[s]:
+                    raise RuntimeError(
+                        "jax sweep: host/device tier accounting diverged "
+                        f"(size {s}: fast_used {final_fast[s]} vs "
+                        f"{pool._fast_used}, rss {final_rss[s]} vs "
+                        f"{pool._rss_pages})"
+                    )
     return times, pools, configs_out, fm_sizes, costs

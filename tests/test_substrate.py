@@ -9,7 +9,7 @@ from repro.checkpoint import CheckpointManager, save_checkpoint, load_checkpoint
 from repro.checkpoint.store import latest_step
 from repro.data import SyntheticLMDataset
 from repro.optim import adamw, cosine_schedule, global_norm
-from repro.runtime import StepWatchdog, StragglerMonitor, retry_step
+from repro.runtime.fault_tolerance import StepWatchdog, StragglerMonitor, retry_step
 from repro.runtime.elastic import plan_mesh
 from repro.runtime.fault_tolerance import StepTimeoutError
 
