@@ -42,7 +42,10 @@ then corrected through the *same* host resolver the numpy sweep uses
 (:func:`repro.tiering.page_pool._resolve_step_victims` over the schedule's
 replayed availability horizons) and a tiny fix-up scatter. Counters are
 schedule-determined and identical either way, so only tier identity is
-patched.
+patched. The resolver reads its key order from the shared ranking: both
+streams are keyed on integer ranks, and a size's winners come in key order
+as the interval's hot set in rank order filtered by that size's winners (a
+size with few winners sorts their ranks instead), so no size sorts heat.
 
 Eligibility (enforced here, routed by :mod:`repro.sim.api`): the policy
 must advertise ``jax_batchable`` (TPP and the trace-pure admission
@@ -85,6 +88,9 @@ from repro.tiering.policy import PolicyOutcome
 _FAST = int(Tier.FAST)
 _SLOW = int(Tier.SLOW)
 _NO_GROUP = np.iinfo(np.int32).max  # tie group of "no winner"
+# an interfering size sorts its winners' ranks itself while fewer than
+# 1/_OWN_ORDER_CUT of the hot set win, and else filters the shared order
+_OWN_ORDER_CUT = 3
 
 
 def _bucket(n: int, floor: int = 128) -> int:
@@ -348,8 +354,8 @@ def _sweep_run_jax(
     return, bit-exact results; see the module docstring for the contract.
     The layers are :mod:`repro.runtime.tracing` spans (``sweep.*``,
     ``interval.*``, ``fixup.*``) with counters of intervals, interfering
-    sizes, merged reclaim events, device dispatches and host<->device
-    bytes; they time and count, and feed nothing back.
+    sizes (and which key order each took), device dispatches and
+    host<->device bytes; they time and count, and feed nothing back.
     """
     with tracing.span("sweep.eligibility"):
         _require_jax_runnable(trace, policy, faults)
@@ -528,6 +534,11 @@ def _sweep_run_jax(
                             hot_grp = np.full(p_pad, _NO_GROUP, dtype=np.int32)
                         hot_slot = np.full(num_pages, p_pad, dtype=np.int32)
                         hot_slot[hot_rank] = np.arange(hot.size, dtype=np.int32)
+                        if rk is not None:
+                            # hot positions in (effective heat, page id)
+                            # order: every interfering size's candidate
+                            # order is a filter of it
+                            hot_by_key = hot_slot[hot_slot < p_pad]
                     if tracing.active():
                         tracing.count("xfer.h2d_bytes", hot_slot.nbytes + hot_grp.nbytes)
                     tracing.count("device.dispatches")
@@ -553,8 +564,11 @@ def _sweep_run_jax(
                                 win = np.asarray(_row(win_mask_d, s))
                             if tracing.active():
                                 tracing.count("xfer.d2h_bytes", vsel.nbytes + win.nbytes)
-                            victims = rk.order[vsel]  # walk order
-                            winners = hot[win[: hot.size]]
+                            vrank = np.flatnonzero(vsel)  # walk order = rank order
+                            victims = rk.order[vrank]
+                            won = win[: hot.size]
+                            widx = np.flatnonzero(won)  # promotion order
+                            winners = hot[widx]
                             if victims.size + winners.size < d_demand[s]:
                                 raise RuntimeError(
                                     "jax sweep: victim supply mismatch (corrupted "
@@ -562,12 +576,20 @@ def _sweep_run_jax(
                                 )
                             with tracing.span("fixup.merge", size=s):
                                 step_events = pools[s]._schedule_events(int(n_cand[s]))
+                                # the merge keys on ranks; the winners' key
+                                # order is read off the shared ranking, or
+                                # sorted where they are few beside it
+                                wrank = hot_rank[widx]
+                                if widx.size * _OWN_ORDER_CUT < hot.size:
+                                    tracing.count("fixup.own_order")
+                                    worder = np.argsort(wrank)
+                                else:
+                                    tracing.count("fixup.shared_order")
+                                    promo = np.empty(hot.size, dtype=np.int64)
+                                    promo[widx] = np.arange(widx.size)
+                                    worder = promo[hot_by_key[won[hot_by_key]]]
                                 base_n, cand_taken = _resolve_step_victims(
-                                    rk.eff[victims],
-                                    victims,
-                                    rk.eff[winners],
-                                    winners,
-                                    step_events,
+                                    vrank, victims, wrank, winners, step_events, worder
                                 )
                             with tracing.span("fixup.patch", size=s):
                                 fix = np.zeros(num_pages, dtype=np.int8)

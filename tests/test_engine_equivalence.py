@@ -22,6 +22,7 @@ from repro.core.telemetry import ConfigVector
 from repro.core.trace import IntervalAccess, Trace
 from repro.core.tuner import TunaTuner, TunerConfig, build_database, scale_config
 from repro.core.watermark import WatermarkController
+from repro.runtime import tracing
 from repro.sim.engine import run_trace, simulate
 from repro.sim.sweep import TunedSlice, sweep_fm_fracs, sweep_tuned
 from repro.tiering import policy as policy_mod
@@ -409,11 +410,15 @@ class TestThrashEquivalence:
                 moved += len(sweep_tuner.controller.log)
         assert moved > 0 and direct > 0  # the scenario must actually thrash
 
+    @pytest.mark.parametrize("keys", ["float", "rank"])
     @pytest.mark.parametrize("seed", list(range(8)))
-    def test_victim_resolver_matches_event_replay(self, seed):
+    def test_victim_resolver_matches_event_replay(self, seed, keys):
         """Property check of the merge itself: random key-sorted base
         streams, random candidate keys and random availability horizons
-        must select exactly the pages a per-event heap replay demotes."""
+        must select exactly the pages a per-event heap replay demotes —
+        keyed on float heat (the resolver sorts the candidates), or on
+        integer ranks of one shared (heat, id) ranking with the
+        candidates' key order handed in (the device sweep's form)."""
         rng = np.random.default_rng(seed)
         n_base = int(rng.integers(0, 60))
         n_cand = int(rng.integers(1, 60))
@@ -436,9 +441,18 @@ class TestThrashEquivalence:
                 demanded += d
         if not events:
             events = [(n_cand, max(1, (n_base + n_cand) // 2))]
-        n_b, taken = _resolve_step_victims(
-            base_eff, base_ids, cand_eff, cand_ids, events
-        )
+        if keys == "float":
+            args = (base_eff, base_ids, cand_eff, cand_ids, events)
+        else:
+            ranking = np.lexsort((np.concatenate([base_ids, cand_ids]),
+                                  np.concatenate([base_eff, cand_eff])))
+            rank = np.empty(ranking.size, dtype=np.int64)
+            rank[ranking] = np.arange(ranking.size)
+            # the candidates' key order is a filter of the shared ranking
+            cand_order = ranking[ranking >= n_base] - n_base
+            args = (rank[:n_base], base_ids, rank[n_base:], cand_ids, events,
+                    cand_order)
+        n_b, taken = _resolve_step_victims(*args)
         # naive replay: per event, pop the d smallest available (eff, id)
         heap, bi, p_prev = [], 0, 0
         got_base, got_cand = 0, set()
@@ -738,6 +752,27 @@ class TestJaxSweepEquivalence:
             pressure_trace(seed, rss=3_000, n_intervals=8),
             [0.8, 0.45, 0.25, 0.1],
         )
+
+    @pytest.mark.parametrize("seed", [0, 2])
+    def test_thrash_resolver_key_orders(self, seed):
+        """Each interfering size takes its winners' key order from the
+        interval's shared ranking, or sorts their ranks itself where few
+        win: the pressure trace's sizes reach both, every resolver call
+        takes one, and the sweep stays bit-identical either way."""
+        tracing.reset()
+        try:
+            with tracing.recording():
+                self._assert_three_lanes(
+                    pressure_trace(seed, rss=3_000, n_intervals=8),
+                    [0.8, 0.45, 0.25, 0.1],
+                )
+            c = tracing.snapshot()["counters"]
+        finally:
+            tracing.reset()
+        shared = c.get("fixup.shared_order", 0)
+        own = c.get("fixup.own_order", 0)
+        assert shared + own == c["sweep.interfering_sizes"]
+        assert shared > 0 and own > 0
 
     @pytest.mark.parametrize("kswapd", [1, 96])
     def test_kswapd_starved(self, kswapd):
