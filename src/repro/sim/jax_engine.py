@@ -353,9 +353,10 @@ def _sweep_run_jax(
     Same signature, same ``(times, pools, configs_out, fm_sizes, costs)``
     return, bit-exact results; see the module docstring for the contract.
     The layers are :mod:`repro.runtime.tracing` spans (``sweep.*``,
-    ``interval.*``, ``fixup.*``) with counters of intervals, interfering
-    sizes (and which key order each took), device dispatches and
-    host<->device bytes; they time and count, and feed nothing back.
+    ``interval.*``, ``fixup.*``) with counters of intervals, touched and
+    hot pages, migrating sizes, commit steps, interfering sizes (and which
+    key order each took), device dispatches and host<->device bytes; they
+    time and count, and feed nothing back.
     """
     with tracing.span("sweep.eligibility"):
         _require_jax_runnable(trace, policy, faults)
@@ -460,6 +461,8 @@ def _sweep_run_jax(
                     # touches, hottest-first candidates and their admission
                     interval_touch[pages] += touches  # ids are unique per interval
                     hot = _hot_sorted(pages, touches, hot_thr)
+                    tracing.count("interval.touched_pages", pages.size)
+                    tracing.count("interval.hot_pages", hot.size)
                     if admit_margin is None:
                         hot_ok = np.ones(hot.size, dtype=bool)
                     else:
@@ -508,6 +511,7 @@ def _sweep_run_jax(
                     tracing.count("xfer.d2h_bytes", counters.nbytes)
                 (pm_pr, pm_de, pm_fail, direct_total, events, d_demand,
                  rejected, n_cand) = counters
+                tracing.count("sweep.migrating_sizes", np.count_nonzero(pm_pr + pm_de))
                 dev_tier = tier_alloc
                 if pm_pr.any() or d_demand.any():
                     with tracing.span("interval.rank"):
@@ -541,6 +545,7 @@ def _sweep_run_jax(
                             hot_by_key = hot_slot[hot_slot < p_pad]
                     if tracing.active():
                         tracing.count("xfer.h2d_bytes", hot_slot.nbytes + hot_grp.nbytes)
+                    tracing.count("sweep.commit_intervals")
                     tracing.count("device.dispatches")
                     with tracing.span("interval.commit"):
                         dev_tier, interf_d, vsel_d = commit_step(

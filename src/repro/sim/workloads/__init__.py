@@ -28,6 +28,10 @@ arrivals`): open/closed-loop session arrivals under Poisson + diurnal +
 flash-crowd rate modulation with long-tail session lifetimes — the
 per-tenant workload of the :mod:`repro.fleet` multi-tenant layer, and a
 bursty-churn stressor for every other engine path.
+
+``ycsb_c`` is YCSB core workload C (:mod:`repro.sim.workloads.ycsb`):
+zipfian reads of 1 KB records through a hash index, a skewed hot set of
+a few percent of the RSS.
 """
 
 from repro.sim.workloads.base import PageMapper
@@ -36,6 +40,7 @@ from repro.sim.workloads.xsbench import xsbench_trace
 from repro.sim.workloads.btree import btree_trace
 from repro.sim.workloads.thrash import thrash_trace
 from repro.sim.workloads.arrivals import arrivals_trace
+from repro.sim.workloads.ycsb import ycsb_trace
 
 WORKLOADS = {
     "bfs": bfs_trace,
@@ -45,8 +50,9 @@ WORKLOADS = {
     "btree": btree_trace,
     "thrash": thrash_trace,
     "arrivals": arrivals_trace,
+    "ycsb_c": ycsb_trace,
 }
 
 __all__ = ["WORKLOADS", "PageMapper", "bfs_trace", "sssp_trace",
            "pagerank_trace", "xsbench_trace", "btree_trace", "thrash_trace",
-           "arrivals_trace"]
+           "arrivals_trace", "ycsb_trace"]
