@@ -16,14 +16,23 @@ per interval:
 
 The size-independent work stays on the host, in the numpy sweep's own
 code: the ``float64`` heat recurrence (:class:`~repro.tiering.page_pool.
-LazyHeat`), the interval's hottest-first candidate order, the admission
-test, and the stable ``(effective heat, page id)`` demotion ranking
-(:class:`~repro.tiering.page_pool.GlobalDemoteRank`). The device receives
-that ranking as an int32 permutation plus int32 *tie groups* (equal
-effective heat ⇔ equal group, ordered like the heat), so it never holds a
-float. This is deliberate: a TPU has no native ``float64``, and a stable
-sort is minutes of TPU compile per shape, while the ranking is computed
-once per interval for every size.
+LazyHeat`), the interval's hottest-first candidate order and the
+admission test. A TPU has no native ``float64``, so the device never
+holds a float.
+
+The stable ``(effective heat, page id)`` demotion ranking, computed once
+per demoting interval for every size, runs on the device as the **rank
+step** (:func:`_rank_step`): the host splits each page's effective heat
+into the two uint32 halves of its IEEE-754 bit pattern, and one integer
+sort by ``(high, low, page id)`` gives the int32
+permutation, its inverse and the *tie groups* (equal effective heat ⇔
+equal group, ordered like the heat). Effective heat is non-negative and
+never NaN, so its bits order exactly like its value: the ranking is
+:class:`~repro.tiering.page_pool.GlobalDemoteRank`'s, bit for bit. On the
+CPU backend the "device" is the host, where numpy's stable argsort is
+faster than XLA:CPU's sort, so there the host builds the same arrays
+(``GlobalDemoteRank`` and :func:`_tie_groups`) and ships them
+(:func:`_rank_on_device` decides).
 
 Exactness contract (pinned by ``tests/test_engine_equivalence.py``):
 
@@ -46,6 +55,9 @@ patched. The resolver reads its key order from the shared ranking: both
 streams are keyed on integer ranks, and a size's winners come in key order
 as the interval's hot set in rank order filtered by that size's winners (a
 size with few winners sorts their ranks instead), so no size sorts heat.
+Where the device ranked, an interval with an interfering size pulls the
+permutation and the hot set's ranks once for this; other intervals pull
+nothing of the ranking.
 
 Eligibility (enforced here, routed by :mod:`repro.sim.api`): the policy
 must advertise ``jax_batchable`` (TPP and the trace-pure admission
@@ -302,6 +314,59 @@ def _fix_row(tier, row, fix):
     return lax.dynamic_update_index_in_dim(tier, new, row, axis=0)
 
 
+def _rank_on_device() -> bool:
+    """Whether the demotion ranking runs as :func:`_rank_step` on the
+    device: everywhere but the CPU backend, where the "device" is the host
+    and XLA:CPU's sort of 2.5M keys takes 1.86 s to numpy's 0.36 s. Read
+    per sweep call, like :func:`repro.kernels.ops.pallas_mode`."""
+    return jax.default_backend() != "cpu"
+
+
+def _heat_keys(eff: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """The high and low uint32 halves of each effective heat's bits: for
+    non-negative, non-NaN doubles, ``(high, low)`` orders like the value."""
+    bits = eff.view(np.uint64)
+    return (bits >> np.uint64(32)).astype(np.uint32), bits.astype(np.uint32)
+
+
+@jax.jit
+def _rank_step(hi, lo):
+    """The shared demotion ranking on the device, from :func:`_heat_keys`.
+
+    One integer sort by ``(hi, lo, page id)`` gives ``GlobalDemoteRank``'s
+    stable ``order``; ``rank_inv`` is its inverse and ``grp``
+    :func:`_tie_groups`, all int32. One executable per page count.
+    """
+    pos = lax.iota(jnp.int32, hi.shape[0])
+    hi_s, lo_s, order = lax.sort((hi, lo, pos), num_keys=3)
+    rank_inv = jnp.zeros_like(pos).at[order].set(pos, unique_indices=True)
+    new = (hi_s[1:] != hi_s[:-1]) | (lo_s[1:] != lo_s[:-1])
+    grp = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(new, dtype=jnp.int32)])
+    return order, rank_inv, grp
+
+
+@jax.jit
+def _hot_ranks(rank_inv, grp, hot_p, n_hot):
+    """The commit step's view of the hot set in a device-ranked interval.
+
+    ``hot_p`` is the hottest-first candidate order padded past ``n_hot``.
+    Returns ``hot_slot`` (by rank position: the candidate's slot in
+    ``hot_p``, else the padded length) and ``hot_grp`` (each slot's tie
+    group, ``_NO_GROUP`` past ``n_hot``), as the host path builds them."""
+    p_pad = hot_p.shape[0]
+    slot = lax.iota(jnp.int32, p_pad)
+    valid = slot < n_hot
+    hot_rank = jnp.take(rank_inv, hot_p)
+    n = rank_inv.shape[0]
+    # padding slots scatter to distinct out-of-range positions, dropped
+    at = jnp.where(valid, hot_rank, n + slot)
+    hot_slot = jnp.full(n, p_pad, jnp.int32).at[at].set(
+        slot, mode="drop", unique_indices=True
+    )
+    hot_grp = jnp.where(valid, jnp.take(grp, hot_rank), _NO_GROUP)
+    return hot_slot, hot_grp
+
+
 def _require_jax_runnable(trace, policy, faults) -> None:
     """The eligibility contract (mirrored by the api.py planner checks)."""
     if faults is not None or policy.fault_injector is not None:
@@ -367,6 +432,7 @@ def _sweep_run_jax(
     admit_margin = getattr(policy, "admit_margin", None)
     schedule_step = _build_schedule_step(hot_thr, policy.promote_batch)
     commit_step = _build_commit_step(pallas_mode())
+    rank_on_device = _rank_on_device()
 
     with jax.enable_x64(True):
         with tracing.span("sweep.setup"):
@@ -485,6 +551,9 @@ def _sweep_run_jax(
                         highf_a[s] = wm.high_free
                         kswapd_a[s] = pool.kswapd_batch
                     p_pad = _bucket(pages.size)
+                    # on the device once: the rank step reads it too
+                    hot_p = jnp.asarray(_pad(hot, p_pad, 0, np.int32))
+                    tracing.count("xfer.h2d_bytes", hot_p.nbytes)
                     step_in = (
                         new_rank,
                         n_fast_arr,
@@ -492,7 +561,7 @@ def _sweep_run_jax(
                         _pad(counts_mem, p_pad, 0, np.int64),
                         _pad(rep, p_pad, 0, np.int64),
                         _pad(np.ones(pages.size, bool), p_pad, False, bool),
-                        _pad(hot, p_pad, 0, np.int32),
+                        hot_p,
                         _pad(np.ones(hot.size, bool), p_pad, False, bool),
                         _pad(hot_ok, p_pad, False, bool),
                         free_a, fastc_a, minf_a, lowf_a, highf_a, kswapd_a,
@@ -518,33 +587,38 @@ def _sweep_run_jax(
                         # --- the shared demotion ranking, only when some
                         # size demotes (promote-only intervals commit in
                         # page order)
-                        if d_demand.any():
-                            rk = GlobalDemoteRank(heat.lookahead_dense() + interval_touch)
-                            grp_np = _tie_groups(rk)
-                            order = jnp.asarray(rk.order.astype(np.int32))
-                            rank_inv = jnp.asarray(rk.rank.astype(np.int32))
-                            grp = jnp.asarray(grp_np)
-                            if tracing.active():
-                                tracing.count("xfer.h2d_bytes",
-                                              order.nbytes + rank_inv.nbytes + grp.nbytes)
-                            hot_rank = rk.rank[hot]
-                            hot_grp = _pad(grp_np[hot_rank], p_pad, _NO_GROUP, np.int32)
+                        if d_demand.any() and rank_on_device:
+                            tracing.count("rank.device")
+                            tracing.count("device.dispatches", 2)
+                            hi, lo = _heat_keys(heat.lookahead_dense() + interval_touch)
+                            tracing.count("xfer.h2d_bytes", hi.nbytes + lo.nbytes)
+                            order, rank_inv, grp = _rank_step(hi, lo)
+                            hot_slot, hot_grp = _hot_ranks(
+                                rank_inv, grp, hot_p, np.int32(hot.size)
+                            )
                         else:
-                            rk = None
-                            if identity is None:
-                                identity = jnp.arange(num_pages, dtype=jnp.int32)
-                            order = rank_inv = grp = identity
-                            hot_rank = hot
-                            hot_grp = np.full(p_pad, _NO_GROUP, dtype=np.int32)
-                        hot_slot = np.full(num_pages, p_pad, dtype=np.int32)
-                        hot_slot[hot_rank] = np.arange(hot.size, dtype=np.int32)
-                        if rk is not None:
-                            # hot positions in (effective heat, page id)
-                            # order: every interfering size's candidate
-                            # order is a filter of it
-                            hot_by_key = hot_slot[hot_slot < p_pad]
-                    if tracing.active():
-                        tracing.count("xfer.h2d_bytes", hot_slot.nbytes + hot_grp.nbytes)
+                            if d_demand.any():
+                                tracing.count("rank.host")
+                                rk = GlobalDemoteRank(heat.lookahead_dense() + interval_touch)
+                                grp_np = _tie_groups(rk)
+                                order = jnp.asarray(rk.order.astype(np.int32))
+                                rank_inv = jnp.asarray(rk.rank.astype(np.int32))
+                                grp = jnp.asarray(grp_np)
+                                if tracing.active():
+                                    tracing.count("xfer.h2d_bytes",
+                                                  order.nbytes + rank_inv.nbytes + grp.nbytes)
+                                hot_rank = rk.rank[hot]
+                                hot_grp = _pad(grp_np[hot_rank], p_pad, _NO_GROUP, np.int32)
+                            else:
+                                if identity is None:
+                                    identity = jnp.arange(num_pages, dtype=jnp.int32)
+                                order = rank_inv = grp = identity
+                                hot_rank = hot
+                                hot_grp = np.full(p_pad, _NO_GROUP, dtype=np.int32)
+                            hot_slot = np.full(num_pages, p_pad, dtype=np.int32)
+                            hot_slot[hot_rank] = np.arange(hot.size, dtype=np.int32)
+                            if tracing.active():
+                                tracing.count("xfer.h2d_bytes", hot_slot.nbytes + hot_grp.nbytes)
                     tracing.count("sweep.commit_intervals")
                     tracing.count("device.dispatches")
                     with tracing.span("interval.commit"):
@@ -561,7 +635,24 @@ def _sweep_run_jax(
                     # and patch the device tier (counters are
                     # schedule-determined and already exact)
                     with tracing.span("interval.fixup"):
-                        for s in np.flatnonzero(interf):
+                        interfering = np.flatnonzero(interf)
+                        if interfering.size:
+                            # what the resolver reads of the ranking: the
+                            # permutation, and the hot set's ranks and its
+                            # positions in (effective heat, page id) order
+                            # (every interfering size's candidate order is
+                            # a filter of it)
+                            with tracing.span("interval.pull"):
+                                rank_order = np.asarray(order)
+                                hot_slot = np.asarray(hot_slot)
+                            if rank_on_device and tracing.active():
+                                tracing.count("xfer.d2h_bytes",
+                                              rank_order.nbytes + hot_slot.nbytes)
+                            hot_at = np.flatnonzero(hot_slot < p_pad)
+                            hot_by_key = hot_slot[hot_at]
+                            hot_rank = np.empty(hot.size, dtype=np.int64)
+                            hot_rank[hot_by_key] = hot_at
+                        for s in interfering:
                             tracing.count("sweep.interfering_sizes")
                             tracing.count("device.dispatches", 3)  # 2 x _row, _fix_row
                             with tracing.span("fixup.pull", size=s):
@@ -570,7 +661,7 @@ def _sweep_run_jax(
                             if tracing.active():
                                 tracing.count("xfer.d2h_bytes", vsel.nbytes + win.nbytes)
                             vrank = np.flatnonzero(vsel)  # walk order = rank order
-                            victims = rk.order[vrank]
+                            victims = rank_order[vrank]
                             won = win[: hot.size]
                             widx = np.flatnonzero(won)  # promotion order
                             winners = hot[widx]

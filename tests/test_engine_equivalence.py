@@ -745,6 +745,7 @@ class TestJaxSweepEquivalence:
             assert jx.stats[i] == ref.stats, f
             assert np.array_equal(jx.interval_times[i], ref.interval_times), f
             assert jx.configs[i] == ref.configs, f
+        return jx
 
     @pytest.mark.parametrize("seed", [0, 2])
     def test_thrash_pressure(self, seed):
@@ -773,6 +774,32 @@ class TestJaxSweepEquivalence:
         own = c.get("fixup.own_order", 0)
         assert shared + own == c["sweep.interfering_sizes"]
         assert shared > 0 and own > 0
+
+    @pytest.mark.parametrize("on_device", [True, False], ids=["device", "host"])
+    @pytest.mark.parametrize("seed", [0, 2])
+    def test_thrash_pressure_rank_paths(self, monkeypatch, seed, on_device):
+        """The demotion ranking on the device (forced here on the CPU) and
+        on the host give the same bit-exact sweep through the thrash
+        fix-up; every demoting interval ranks once, on the forced path."""
+        pytest.importorskip("jax")
+        from repro.sim import jax_engine
+
+        monkeypatch.setattr(jax_engine, "_rank_on_device", lambda: on_device)
+        tracing.reset()
+        try:
+            with tracing.recording():
+                jx = self._assert_three_lanes(
+                    pressure_trace(seed, rss=3_000, n_intervals=8),
+                    [0.8, 0.45, 0.25, 0.1],
+                )
+            c = tracing.snapshot()["counters"]
+        finally:
+            tracing.reset()
+        demoting = np.any([[cv.pm_de > 0 for cv in cfgs] for cfgs in jx.configs], axis=0)
+        taken, other = ("rank.device", "rank.host")[:: 1 if on_device else -1]
+        assert c[taken] == int(demoting.sum()) > 0
+        assert c.get(other, 0) == 0
+        assert c["sweep.interfering_sizes"] > 0
 
     @pytest.mark.parametrize("kswapd", [1, 96])
     def test_kswapd_starved(self, kswapd):

@@ -144,3 +144,34 @@ def test_device_sweep_matches_numpy_and_reference_pool(monkeypatch):
     assert counters["sweep.migrating_sizes"] == int(migrating.sum())
     assert counters["sweep.commit_intervals"] == int(migrating.any(axis=0).sum())
     assert 0 < counters["sweep.commit_intervals"] < len(tr)  # the load interval migrates nothing
+
+
+@pytest.mark.parametrize("on_device", [True, False], ids=["device", "host"])
+def test_device_sweep_rank_paths(monkeypatch, on_device):
+    """The demotion ranking on the device (forced here on the CPU) and on
+    the host: the jax sweep == the numpy sweep == ``ReferencePagePool`` at
+    every size, and every demoting interval ranks once, on the forced path."""
+    from repro.sim import jax_engine
+
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    monkeypatch.setattr(jax_engine, "_rank_on_device", lambda: on_device)
+    make = functools.partial(ycsb.ycsb_trace, **SMALL, seed=2**31 + 12)
+    tracing.reset()
+    try:
+        with tracing.recording():
+            jx = _run("jax", make)
+        counters = tracing.snapshot()["counters"]
+    finally:
+        tracing.reset()
+    base = _run("numpy", make)
+    ref = _run("auto", make, pool_factory=ReferencePagePool)
+    for rj, rn, rr in zip(jx.runs, base.runs, ref.runs):
+        for other in (rn, rr):
+            assert rj.result.stats == other.result.stats, rj.fm_frac
+            assert np.array_equal(rj.result.interval_times, other.result.interval_times)
+            assert rj.result.configs == other.result.configs
+
+    demoting = np.any([[c.pm_de > 0 for c in r.result.configs] for r in base.runs], axis=0)
+    taken, other = ("rank.device", "rank.host")[:: 1 if on_device else -1]
+    assert counters[taken] == int(demoting.sum()) > 0
+    assert counters.get(other, 0) == 0
