@@ -9,8 +9,8 @@ TPP-only baseline and every TPP+Tuna variant are policy specs of the same
 scenario, the tuners are constructed inside :func:`repro.sim.api.run` from
 their :class:`~repro.sim.api.TunerSpec`, and the planner executes the whole
 set as **one batched tuned sweep** per workload — each trace runs once
-instead of once per configuration, bit-exact against the old per-run
-``simulate(..., tuner=...)`` path (pinned by ``tests/test_api.py`` /
+instead of once per configuration, bit-exact against one per-size
+``_simulate`` run per configuration (pinned by ``tests/test_api.py`` /
 ``tests/test_engine_equivalence.py``).
 
 Paper: savings up to 16% (Btree); overall loss XSBench 1.8%, BFS 2%,

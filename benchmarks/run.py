@@ -1,7 +1,7 @@
 """Benchmark driver: one module per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows. Kernel and roofline benches
-are included after the paper-reproduction set. A failed suite is reported
+Prints ``name,us_per_call,derived`` CSV rows (CPU host times; speed on
+the chip is measured by the ``bench/`` cells). A failed suite is reported
 and the rest still run; the exit code is then non-zero (``--strict``
 stops at the first failure instead).
 
@@ -30,8 +30,6 @@ def main() -> int:
         fig_fleet,
         fig_model_fidelity,
         serving_tiered,
-        bench_engine,
-        kernels as kernel_bench,
     )
 
     suites = [
@@ -44,8 +42,6 @@ def main() -> int:
         ("fleet", fig_fleet),
         ("fidelity", fig_model_fidelity),
         ("serving", serving_tiered),
-        ("engine", bench_engine),
-        ("kernels", kernel_bench),
     ]
     print("name,us_per_call,derived")
     failed = []

@@ -11,8 +11,8 @@ The measured side — the full-fm baseline plus every FM_GRID size — is one
 declarative experiment per workload whose policy axis carries every
 registered migrating backend (tpp, admission, thrash_guard); the
 :func:`repro.sim.api.run` planner executes it as one batched sweep per
-backend instead of ``kinds * (1 + len(FM_GRID))`` separate ``simulate()``
-passes, memoized under ``benchmarks/_cache``. The per-size error rows are
+backend instead of ``kinds * (1 + len(FM_GRID))`` separate per-size
+runs, memoized under ``benchmarks/_cache``. The per-size error rows are
 reported for the paper's TPP configuration; a per-kind summary row then
 shows how the TPP-built database's predictions degrade under the other
 management systems (the model-transfer question the policy API exists to

@@ -11,8 +11,8 @@ The whole target/interval matrix — seven tuner configurations plus the
 TPP-only baseline — is one declarative experiment over the SSSP trace
 (eight policy specs of a single scenario), which the
 :func:`repro.sim.api.run` planner executes as **one** batched tuned sweep
-instead of the old fifteen per-configuration ``simulate()`` passes (each
-old run also re-ran its own baseline).
+instead of fifteen per-configuration per-size runs, each of which would
+re-run its own baseline.
 """
 
 from __future__ import annotations

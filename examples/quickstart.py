@@ -13,9 +13,10 @@ results come back as a serializable `RunSet` (try `rs.to_json()`).
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 
-CI executes this file with `-W "error:repro.sim:DeprecationWarning"`
-(every shim's message starts with "repro.sim."), so it can never regress
-onto the deprecated `simulate`/`sweep_*` entry points.
+To check an engine change against the frozen seed pool, run the same
+scenario as `Scenario(trace=..., pool_factory=ReferencePagePool)`
+(`repro.tiering.reference_pool`) and compare `stats` and `interval_times`
+for exact equality. Speed is measured on the chip by the `bench/` cells.
 """
 
 import functools

@@ -44,13 +44,6 @@ backend/policy PR adds its invariant as one rule file; no core edits.
     silently freeze into the traced executable. Reachability is the
     module-local call graph from jit roots (decorated functions,
     ``jax.jit(f)`` arguments, ``pl.pallas_call`` kernels).
-``TUNA005`` *no-shim-callers*
-    Internal (``src/``) callers of the ``DeprecationWarning`` shims
-    ``simulate`` / ``sweep_fm_fracs`` / ``sweep_tuned`` /
-    ``sweep_times``. Production code goes through
-    :func:`repro.sim.api.run`; previously only the quickstart smoke's
-    ``-W error`` filter caught regressions, and only on the paths the
-    quickstart exercises.
 ``TUNA006`` *runset-schema*
     RunSet schema drift in ``sim/api.py``: the set of serialized field
     names in ``RunSet.to_json`` (plus the result/decision encoders) is

@@ -14,7 +14,6 @@ from repro.analysis.rules import (  # noqa: F401  (import = register)
     tuna002_pool_tier_writes,
     tuna003_frozen_module,
     tuna004_jit_purity,
-    tuna005_no_shim_callers,
     tuna006_runset_schema,
     tuna007_trace_determinism,
     tuna008_picklable_specs,

@@ -97,8 +97,8 @@ class TunaTuner:
         """Attach the pool this tuner actuates (via its controller).
 
         The single entry point both execution paths use to wire a tuner
-        into a run: :func:`repro.sim.engine.simulate` binds its one pool,
-        and :func:`repro.sim.sweep.sweep_tuned` binds each size-slice's
+        into a run: :func:`repro.sim.engine._simulate` binds its one pool,
+        and :func:`repro.sim.sweep._sweep_tuned` binds each size-slice's
         pool to that slice's tuner. ``peak_rss_pages`` anchors the tuner's
         fm-fraction arithmetic (defaults to the pool's hardware capacity).
         Returns self.

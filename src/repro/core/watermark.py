@@ -32,7 +32,7 @@ class WatermarkController:
 
     ``pool`` may be left ``None`` at construction and bound later via
     :meth:`bind` (or :meth:`repro.core.tuner.TunaTuner.bind_pool`): the
-    batched tuned sweep (:func:`repro.sim.sweep.sweep_tuned`) builds its
+    batched tuned sweep (:func:`repro.sim.sweep._sweep_tuned`) builds its
     per-size slice pools only once the trace is known, so controllers —
     like the tuners that own them — are created unbound and attached to
     their slice at sweep start.

@@ -174,24 +174,3 @@ def strided_probe(fast_arr, slow_arr, fast_idx, slow_idx, ai_iters: int):
         fast_arr, slow_arr, fast_idx, slow_idx, ai_iters,
         interpret=mode == "interpret",
     )
-
-
-# ------------------------------------------------------------ bench hooks
-def _bench_attention():
-    q = jnp.ones((2, 128, 8, 64), jnp.bfloat16)
-    k = jnp.ones((2, 128, 4, 64), jnp.bfloat16)
-    return jax.jit(attention)(q, k, k).block_until_ready()
-
-
-def _bench_wkv6():
-    B, S, H, hd = 2, 64, 4, 32
-    r = jnp.ones((B, S, H, hd), jnp.float32) * 0.1
-    u = jnp.zeros((H, hd))
-    o, _ = jax.jit(wkv6)(r, r, r, r * 0.5, u)
-    return o.block_until_ready()
-
-
-BENCH_CASES = {
-    "attention_2x128": _bench_attention,
-    "wkv6_2x64": _bench_wkv6,
-}

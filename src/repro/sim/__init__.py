@@ -14,7 +14,7 @@ DMA/latency backend.
 """
 
 from repro.sim.costmodel import HardwareProfile, OPTANE_LIKE, TPU_V5E_TIER
-from repro.sim.engine import run_trace, simulate, SimResult
+from repro.sim.engine import run_trace, SimResult
 from repro.sim.api import (
     Experiment,
     PolicySpec,
@@ -30,7 +30,6 @@ __all__ = [
     "OPTANE_LIKE",
     "TPU_V5E_TIER",
     "run_trace",
-    "simulate",
     "SimResult",
     "Experiment",
     "PolicySpec",

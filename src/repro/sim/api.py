@@ -78,10 +78,11 @@ that raises inside a worker is re-raised in the parent as
 worker raises instead of blocking forever); a broken executor (OOM-killed
 worker, fork ban) gets ONE fresh executor for the unfinished scenarios
 before the planner falls back to serial execution. Every backend is
-bit-exact against the pre-redesign entry points (``simulate`` /
-``sweep_fm_fracs`` / ``sweep_tuned``), which ``tests/test_api.py`` pins —
+bit-exact against the engine functions it dispatches to (``_simulate`` /
+``_sweep_fm_fracs`` / ``_sweep_tuned``), which ``tests/test_api.py`` pins —
 counters, interval times, config vectors, tuner decision lists, watermark
-event logs.
+event logs. This is the one entry point; speed is measured on the chip by
+the ``bench/`` cells.
 
 Fault model (``Scenario.faults``)
 ---------------------------------
@@ -156,7 +157,7 @@ still loads v1–v3 documents (missing keys take their defaults)::
 ``runs`` order is deterministic: scenario-major (experiment order), then
 policy order, then size order. ``chunked_step_count`` counts only the sweep
 backends — the per-size ``simulate`` fallback may legitimately execute the
-chunked loop; the sweeps must not, and the engine benchmark asserts it.
+chunked loop; the sweeps must not, and the equivalence tests assert it.
 The count is aggregated from the *per-policy-instance* counters
 (:attr:`repro.tiering.policy.MigrationPolicy.chunked_steps`) of the
 instances this run constructed, so concurrent ``run()`` calls and fan-out

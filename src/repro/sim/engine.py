@@ -9,17 +9,16 @@ watermarks) and accumulates time from the cost model. Used for three jobs:
    accuracy and runtime tuning (the paper's evaluation);
 3. executing workloads **with the Tuna tuner in the loop** (TPP+Tuna).
 
-Since the unified experiment API landed, :func:`simulate` is a deprecated
-entry point: describe runs declaratively with
-:class:`repro.sim.api.Scenario` / :class:`repro.sim.api.Experiment` and
-execute them through :func:`repro.sim.api.run`, whose planner falls back to
-the per-size engine loop here (:func:`_simulate`) only for specs the
-batched sweeps cannot absorb (custom ``pool_factory``, non-TPP policies).
+Runs are described with :class:`repro.sim.api.Scenario` /
+:class:`repro.sim.api.Experiment` and executed through
+:func:`repro.sim.api.run`, whose planner falls back to the per-size engine
+loop here (:func:`_simulate`) only for specs the batched sweeps cannot
+absorb (custom ``pool_factory``, non-TPP policies). The tests call
+:func:`_simulate` directly as the per-size oracle.
 """
 
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -77,7 +76,7 @@ def _simulate(
     stepped every ``tune_every`` intervals (the 2.5 s tuning interval mapped
     onto profiling intervals) and drives the watermarks itself.
     ``pool_factory`` swaps the pool implementation (the equivalence tests
-    and the engine benchmark run the same trace through
+    run the same trace through
     :class:`repro.tiering.reference_pool.ReferencePagePool`).
     ``faults`` (a :class:`repro.sim.faults.FaultSpec` or a pre-built
     injector) turns on the deterministic fault model; ``None`` keeps the
@@ -179,45 +178,6 @@ def _simulate(
         fm_sizes=np.array(fm_sizes, dtype=np.int64),
         stats=pool.stats.snapshot(),
         costs=costs,
-    )
-
-
-def simulate(
-    trace: Trace,
-    fm_frac: float = 1.0,
-    policy: MigrationPolicy | None = None,
-    hw: HardwareProfile = OPTANE_LIKE,
-    hw_capacity_pages: int | None = None,
-    tuner: TunaTuner | None = None,
-    tune_every: int | None = None,
-    seed: int = 0,
-    pool_factory=TieredPagePool,
-    faults: FaultSpec | FaultInjector | None = None,
-) -> SimResult:
-    """Deprecated entry point; see :func:`repro.sim.api.run`.
-
-    Kept as a thin shim over :func:`_simulate` (identical results) for
-    external callers and for the equivalence tests that pin the unified
-    API against the pre-redesign paths.
-    """
-    warnings.warn(
-        "repro.sim.engine.simulate() is deprecated; describe the run with "
-        "repro.sim.api.Scenario/Experiment and execute it via "
-        "repro.sim.api.run()",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    return _simulate(
-        trace,
-        fm_frac=fm_frac,
-        policy=policy,
-        hw=hw,
-        hw_capacity_pages=hw_capacity_pages,
-        tuner=tuner,
-        tune_every=tune_every,
-        seed=seed,
-        pool_factory=pool_factory,
-        faults=faults,
     )
 
 

@@ -25,9 +25,8 @@ Design contract
   injector exists; every integration point is a single ``is not None``
   check outside the vectorized inner loops, and all equivalence lanes
   stay bit-exact (``tests/test_engine_equivalence.py`` /
-  ``tests/test_api.py``; ``bench_engine --quick --gate`` times the same
-  lanes). A zero-rate :class:`FaultSpec` is also bit-exact — the
-  injector filters nothing and logs nothing.
+  ``tests/test_api.py``). A zero-rate :class:`FaultSpec` is also
+  bit-exact — the injector filters nothing and logs nothing.
 * **Visible to the model**: retry-exhausted promotions are credited into
   ``pool.stats.pgpromote_fail`` and the interval's
   :class:`~repro.tiering.policy.PolicyOutcome.pm_fail` — the same

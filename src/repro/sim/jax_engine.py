@@ -756,7 +756,7 @@ def _sweep_run_jax(
                             t_now[s] += cost.total
                 with tracing.span("interval.fold"):
                     _fold_heat(heat, interval_touch, pages)
-                # --- per-slice tuner steps (simulate() order: after the fold)
+                # --- per-slice tuner steps (_simulate() order: after the fold)
                 if tuned:
                     with tracing.span("interval.tune"):
                         for s, tuner in enumerate(tuners):

@@ -8,13 +8,11 @@ executed through :func:`repro.sim.api.run`, whose planner dispatches onto
 the batched sweeps here (:func:`_sweep_fm_fracs` for untuned size vectors,
 :func:`_sweep_tuned` for tuner-in-the-loop slices) and falls back to the
 per-size engine loop (:func:`repro.sim.engine._simulate`) only for specs
-the sweeps cannot absorb. The public names ``sweep_fm_fracs`` /
-``sweep_tuned`` / ``sweep_times`` remain as deprecated shims with
-identical results.
+the sweeps cannot absorb.
 
 Tuna's offline component executes the same micro-benchmark trace at ~21
 fast-memory sizes (paper Sections 3.3/5). Running :func:`repro.sim.engine.
-simulate` once per size repeats every size-independent computation — trace
+_simulate` once per size repeats every size-independent computation — trace
 iteration, LLC absorption, MLP estimation, and the whole hotness bookkeeping
 — 21 times. This module simulates **one trace across the whole size vector
 in a single pass**:
@@ -45,7 +43,7 @@ in a single pass**:
   dropping to the per-size chunked loop. Sweeps are chunked-loop-free end
   to end; the policy instance's per-instance ``chunked_steps`` counter
   records any fallback executions (surfaced by the unified API as
-  ``RunSet.chunked_step_count``) and the engine benchmark asserts it
+  ``RunSet.chunked_step_count``) and the equivalence tests assert it
   stays zero.
 
 Policies are pluggable: :func:`_sweep_fm_fracs` / :func:`_sweep_tuned`
@@ -55,8 +53,8 @@ TPPPolicy`); the :mod:`repro.sim.api` planner constructs it from the
 ``POLICIES`` registry, so admission-controlled and thrash-responsive
 backends ride the exact same vectorized decision batch.
 
-Tuned-sweep mode (:func:`sweep_tuned`)
---------------------------------------
+Tuned-sweep mode (:func:`_sweep_tuned`)
+---------------------------------------
 Each size-slice can carry **live actuation state**: a
 :class:`~repro.core.tuner.TunaTuner` + :class:`~repro.core.watermark.
 WatermarkController` pair per slice, described by a :class:`TunedSlice`.
@@ -71,7 +69,7 @@ through its own cursor, exactly as in the fixed-size sweep. A slice with
 ``tuner=None`` is a plain fixed-size run, which is how the TPP-only
 baseline rides along in the same pass. Results come back as one
 :class:`~repro.sim.engine.SimResult` per slice, bit-exact against
-``simulate(trace, fm_frac=..., tuner=..., tune_every=...)`` — migration
+``_simulate(trace, fm_frac=..., tuner=..., tune_every=...)`` — migration
 counters, interval times, config vectors, per-interval fm sizes, tuner
 decisions and watermark event logs — which
 ``tests/test_engine_equivalence.py`` asserts (anchored, like every engine
@@ -79,43 +77,19 @@ path, on the frozen :class:`~repro.tiering.reference_pool.
 ReferencePagePool` golden model).
 
 Exactness: every per-size arithmetic sequence matches a standalone
-``simulate(trace, fm_frac=f)`` bit for bit (integer counters; float times),
+``_simulate(trace, fm_frac=f)`` bit for bit (integer counters; float times),
 which ``tests/test_engine_equivalence.py`` asserts.
 
-Benchmark tracking
-------------------
-``benchmarks/bench_engine.py`` measures both sweep modes against the seed
-per-size path and persists the trajectory to ``BENCH_engine.json``. On top
-of the PR-1 schema (``bench_db_path_{seed_s,new_s,speedup}``,
-``intervals_per_s_{seed,new}``) the tuned path adds
-``tuned_path_seed_s`` / ``tuned_path_new_s`` / ``tuned_path_speedup``
-(TPP+Tuna closed loop: per-target ``simulate(..., tuner=...)`` vs one
-:func:`sweep_tuned` pass), ``tuned_targets`` (the loss-target vector
-swept), ``tuned_outputs_identical`` (the equivalence gate that ran before
-timing), and ``quick`` (whether the CI quick mode produced the file).
-``thrash_path_seed_s`` / ``thrash_path_new_s`` / ``thrash_path_speedup``
-/ ``thrash_path_ratio`` track the thrash scenario (hot set ~2x the fast
-tier, rotating): a fixed-size sweep deep in the migration-failure regime,
-seed per-size reference loop vs one sweep pass, with
-``thrash_sweep_chunked_steps`` asserting the sweep never executed the
-chunked loop (surfaced by ``RunSet.chunked_step_count`` since the bench
-moved onto the unified API). ``admission_path_{seed_s,new_s,speedup,
-ratio}`` runs the same churn scenario under the registry-routed
-``admission`` policy backend (plus ``admission_rejects`` /
-``admission_sweep_chunked_steps``), so the pluggable backends' sweep path
-is benchmark-gated exactly like TPP's.
-
-Alongside this BENCH schema, experiment results themselves have a
-serialized form: the versioned **RunSet JSON schema**
+Experiment results have a serialized form: the versioned **RunSet JSON schema**
 (``tuna-runset-v2`` — spec echo incl. policy ``params``, per-run results,
 tuner decisions, watermark logs, ``chunked_step_count`` provenance),
 documented in full in the :mod:`repro.sim.api` module docstring and
-round-trip-tested by ``tests/test_api.py``.
+round-trip-tested by ``tests/test_api.py``. Speed is measured on the
+chip by the ``bench/`` cells.
 """
 
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass
 
 import numpy as np
@@ -161,7 +135,7 @@ class TunedSlice:
     ``tuner`` (with its :class:`~repro.core.watermark.WatermarkController`,
     which may be constructed unbound — the sweep binds it to the slice's
     pool) is stepped every ``tune_every`` profiling intervals, mirroring
-    ``simulate(trace, fm_frac=fm_frac, tuner=tuner,
+    ``_simulate(trace, fm_frac=fm_frac, tuner=tuner,
     tune_every=tune_every)``. ``tuner=None`` gives a plain fixed-size run
     (the TPP-only baseline slice).
     """
@@ -361,7 +335,7 @@ def _sweep_run(
         if profilers is None:
             pacc_f_all = (fast_f @ counts_f).astype(np.int64)
         else:
-            # what simulate()'s profiler records per interval, batched in
+            # what _simulate()'s profiler records per interval, batched in
             # one GEMM: reported touches saturate at hot_thr, warm =
             # below-threshold fast-tier observations
             rep = np.minimum(ia.touches, hot_thr)
@@ -479,14 +453,14 @@ def _sweep_run(
             times[s, i] = cost.total
             costs[s].append(cost)
             if tuned:
-                # what simulate() records *before* the tuner step: the fm
+                # what _simulate() records *before* the tuner step: the fm
                 # size in effect during this interval
                 fm_sizes[s, i] = pool.effective_fm_size
                 t_now[s] += cost.total
         # --- one shared heat fold for all sizes (mirrors
         # TieredPagePool.end_interval's dense/indexed hybrid)
         _fold_heat(heat, interval_touch, pages)
-        # --- per-slice tuner steps (simulate() order: after end_interval);
+        # --- per-slice tuner steps (_simulate() order: after end_interval);
         # watermark moves re-partition this slice's stacked tier row from
         # the next interval on — the shared ranking is size-independent
         # and needs no invalidation
@@ -537,7 +511,7 @@ def _sweep_fm_fracs(
 ) -> SweepResult:
     """Run ``trace`` once, concurrently at every fraction in ``fm_fracs``.
 
-    Equivalent to ``[simulate(trace, fm_frac=f, policy=TPPPolicy(hot_thr))
+    Equivalent to ``[_simulate(trace, fm_frac=f, policy=TPPPolicy(hot_thr))
     for f in fm_fracs]`` (same counters, same interval times), at roughly
     the cost of the most expensive single size plus one cross-size
     vectorized policy step per interval. ``kswapd_batch`` overrides every
@@ -597,7 +571,7 @@ def _sweep_tuned(
     The TPP+Tuna closed loop at sweep speed: every slice's tuner runs *in
     the loop* against its own slice pool while the trace is swept once.
     Returns one :class:`~repro.sim.engine.SimResult` per slice, in order —
-    bit-exact against ``simulate(trace, fm_frac=sl.fm_frac,
+    bit-exact against ``_simulate(trace, fm_frac=sl.fm_frac,
     tuner=sl.tuner, tune_every=sl.tune_every)`` per slice (counters,
     interval times, config vectors, fm sizes; the tuner's decision list
     and its controller's watermark event log accumulate identically).
@@ -656,86 +630,3 @@ def _resolve_engine(engine: str):
 
         return _sweep_run_jax
     raise ValueError(f"unknown sweep engine {engine!r} (use 'numpy' or 'jax')")
-
-
-def _deprecated(name: str) -> None:
-    warnings.warn(
-        f"repro.sim.sweep.{name}() is deprecated; describe the run with "
-        "repro.sim.api.Scenario/Experiment and execute it via "
-        "repro.sim.api.run()",
-        DeprecationWarning,
-        stacklevel=3,
-    )
-
-
-def sweep_fm_fracs(
-    trace: Trace,
-    fm_fracs,
-    hot_thr: int = 4,
-    hw: HardwareProfile = OPTANE_LIKE,
-    hw_capacity_pages: int | None = None,
-    seed: int = 0,
-    collect_configs: bool = False,
-    kswapd_batch: int | None = None,
-    policy=None,
-) -> SweepResult:
-    """Deprecated entry point; see :func:`repro.sim.api.run`.
-
-    Thin shim over :func:`_sweep_fm_fracs` with identical results.
-    """
-    _deprecated("sweep_fm_fracs")
-    return _sweep_fm_fracs(
-        trace, fm_fracs, hot_thr=hot_thr, hw=hw,
-        hw_capacity_pages=hw_capacity_pages, seed=seed,
-        collect_configs=collect_configs, kswapd_batch=kswapd_batch,
-        policy=policy,
-    )
-
-
-def sweep_tuned(
-    trace: Trace,
-    slices,
-    hot_thr: int = 4,
-    hw: HardwareProfile = OPTANE_LIKE,
-    hw_capacity_pages: int | None = None,
-    seed: int = 0,
-    kswapd_batch: int | None = None,
-    policy=None,
-) -> list:
-    """Deprecated entry point; see :func:`repro.sim.api.run`.
-
-    Thin shim over :func:`_sweep_tuned` with identical results.
-    """
-    _deprecated("sweep_tuned")
-    return _sweep_tuned(
-        trace, slices, hot_thr=hot_thr, hw=hw,
-        hw_capacity_pages=hw_capacity_pages, seed=seed,
-        kswapd_batch=kswapd_batch, policy=policy,
-    )
-
-
-def sweep_times(
-    trace: Trace,
-    fm_fracs,
-    hot_thr: int = 4,
-    hw: HardwareProfile = OPTANE_LIKE,
-) -> np.ndarray:
-    """Total execution time per fm fraction (the database-build backend).
-
-    Deprecated entry point, deduped onto the :func:`repro.sim.api.run`
-    planner: one untuned :class:`~repro.sim.api.Experiment` over the size
-    vector, which the planner executes as a single batched sweep —
-    identical times to the pre-redesign direct ``sweep_fm_fracs`` call.
-    """
-    _deprecated("sweep_times")
-    from repro.sim.api import Experiment, PolicySpec, Scenario, run
-
-    rs = run(
-        Experiment(
-            name="sweep_times",
-            scenarios=[Scenario(trace=trace, hw=hw)],
-            fm_fracs=tuple(float(f) for f in np.asarray(fm_fracs).ravel()),
-            policies=[PolicySpec(hot_thr=hot_thr)],
-        )
-    )
-    return np.array([rec.result.total_time for rec in rs.runs])

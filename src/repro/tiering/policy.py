@@ -79,21 +79,17 @@ Chunked-loop telemetry
 Every policy instance counts executions of the per-chunk Python fallback
 loop in :attr:`MigrationPolicy.chunked_steps`. The bulk path covers every
 in-engine regime including thrash, so the sweep engines are expected to
-keep their policy instance's counter at zero — the engine benchmark and
-the equivalence tests assert it, and :class:`repro.sim.api.RunSet`
-surfaces the sweep backends' total as provenance. Every candidate-bearing
-chunked execution counts, whatever the pool: pools without a bulk path
-(the reference pool runs chunked by design) increment it too. The
-module-level :func:`chunked_step_count` / :func:`reset_chunked_step_count`
-functions are deprecated shims over a thread-local aggregate of the same
-events; per-instance counters are the supported surface (a process-wide
-global would let concurrent ``run()`` workers cross-pollute provenance).
+keep their policy instance's counter at zero — the equivalence tests
+assert it, and :class:`repro.sim.api.RunSet` surfaces the sweep backends'
+total as provenance. Every candidate-bearing chunked execution counts,
+whatever the pool: pools without a bulk path (the reference pool runs
+chunked by design) increment it too. The counter lives on the instance,
+not the module, so concurrent ``run()`` workers cannot cross-pollute
+each other's provenance.
 """
 
 from __future__ import annotations
 
-import threading
-import warnings
 import weakref
 from dataclasses import dataclass
 
@@ -141,45 +137,6 @@ def resolve_policy(kind: str) -> type:
             f"unknown policy kind {kind!r}; registered kinds: "
             f"{', '.join(sorted(POLICIES))}"
         ) from None
-
-
-# --------------------------------------------- chunked-fallback telemetry
-
-_tls = threading.local()
-
-
-def _count_chunked(policy) -> None:
-    policy.chunked_steps += 1
-    _tls.chunked = getattr(_tls, "chunked", 0) + 1
-
-
-def chunked_step_count() -> int:
-    """Deprecated: thread-local aggregate of chunked-loop executions.
-
-    Read the per-instance :attr:`MigrationPolicy.chunked_steps` counter
-    instead (the sweeps' totals are surfaced as
-    ``RunSet.chunked_step_count``).
-    """
-    warnings.warn(
-        "repro.tiering.policy.chunked_step_count() is deprecated; read "
-        "the per-instance MigrationPolicy.chunked_steps counter (the "
-        "unified API surfaces it as RunSet.chunked_step_count)",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    return getattr(_tls, "chunked", 0)
-
-
-def reset_chunked_step_count() -> None:
-    """Deprecated: reset this thread's chunked-loop aggregate."""
-    warnings.warn(
-        "repro.tiering.policy.reset_chunked_step_count() is deprecated; "
-        "construct a fresh policy instance and read its chunked_steps "
-        "counter instead",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    _tls.chunked = 0
 
 
 @dataclass
@@ -380,7 +337,7 @@ class TPPPolicy(MigrationPolicy):
             # verified invariants (unique, all slow)
             promote = getattr(pool, "_promote_cand", pool.promote)
         if cand.size:
-            _count_chunked(self)
+            self.chunked_steps += 1
         # Promotion is interleaved with background reclaim (TPP decouples
         # allocation and reclaim): promote only into the headroom above the
         # min watermark, let kswapd restore the watermark, repeat. Direct

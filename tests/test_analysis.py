@@ -131,25 +131,6 @@ RULE_FIXTURES = {
             "# tuna: ignore[TUNA004] fixture: no bit-exact contract\n"
         ),
     },
-    "TUNA005": {
-        "path": "src/repro/core/driver.py",
-        "flagged": (
-            "from repro.sim.engine import simulate\n"
-            "def go(tr):\n"
-            "    return simulate(tr, fm_frac=0.5)\n"
-        ),
-        "clean": (
-            "from repro.sim.api import Experiment, Scenario, run\n"
-            "def go(tr):\n"
-            "    return run(Experiment(scenarios=[Scenario(trace=tr)]))\n"
-        ),
-        "suppressed": (
-            "from repro.sim.engine import simulate\n"
-            "def go(tr):\n"
-            "    return simulate(tr, fm_frac=0.5)  "
-            "# tuna: ignore[TUNA005] fixture: oracle\n"
-        ),
-    },
     "TUNA006": {
         "path": "src/repro/sim/api.py",
         # unpinned schema fingerprint is the finding; pinning it (the
@@ -217,9 +198,9 @@ RULE_FIXTURES = {
     "TUNA010": {
         "path": "src/repro/timing/probe.py",
         "flagged": (
-            "from repro.sim.engine import simulate\n"
+            "from repro.sim.engine import _simulate\n"
             "def clock(trace):\n"
-            "    return simulate(trace)\n"
+            "    return _simulate(trace)\n"
         ),
         "clean": (
             "from repro.sim.costmodel import HardwareProfile\n"
@@ -227,7 +208,7 @@ RULE_FIXTURES = {
             "    return hw.lat_fast\n"
         ),
         "suppressed": (
-            "from repro.sim.engine import simulate  "
+            "from repro.sim.engine import _simulate  "
             "# tuna: ignore[TUNA010] fixture: teaching example\n"
         ),
     },
@@ -414,20 +395,6 @@ class TestRuleEdges:
         res, _ = analyze(tmp_path, select=["TUNA004"])
         msgs = " ".join(f.message for f in res.findings)
         assert "print()" in msgs and "time.time()" in msgs
-
-    def test_tuna005_tests_exempt(self, tmp_path):
-        write_tree(
-            tmp_path,
-            {
-                "tests/test_x.py": (
-                    "from repro.sim.engine import simulate\n"
-                    "def test_oracle(tr):\n"
-                    "    assert simulate(tr) is not None\n"
-                )
-            },
-        )
-        res, _ = analyze(tmp_path, select=["TUNA005"])
-        assert res.findings == []
 
     def test_tuna007_launch_exempt(self, tmp_path):
         write_tree(

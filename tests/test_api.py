@@ -1,18 +1,16 @@
-"""The unified experiment API is pinned bit-exact against the
-pre-redesign entry points.
+"""The unified experiment API is pinned bit-exact against the engine
+functions it dispatches to.
 
-``repro.sim.api.run`` is a planner over the same execution backends the
-old entry points exposed directly, so every cell of a ``RunSet`` must
-reproduce ``simulate`` / ``sweep_fm_fracs`` / ``sweep_tuned`` exactly:
-migration counters, interval times, config vectors, per-interval fm
-sizes, tuner decision lists, and watermark event logs. On top of that:
-backend selection, chunked-loop-free sweep provenance, process fan-out
-determinism, lossless ``RunSet`` JSON round-trips, and the deprecation
-shims (each warns once and returns results identical to ``run()``).
+``repro.sim.api.run`` is a planner over the execution backends, so every
+cell of a ``RunSet`` must reproduce ``_simulate`` / ``_sweep_fm_fracs`` /
+``_sweep_tuned`` called directly: migration counters, interval times,
+config vectors, per-interval fm sizes, tuner decision lists, and watermark
+event logs. On top of that: backend selection, chunked-loop-free sweep
+provenance, process fan-out determinism, and lossless ``RunSet`` JSON
+round-trips.
 """
 
 import functools
-import warnings
 
 import numpy as np
 import pytest
@@ -618,91 +616,6 @@ class TestRunSetSerialization:
         assert back.result(scenario="svc") == {"p99": 1.25, "n": 3}
 
 
-class TestDeprecatedShims:
-    """Each pre-redesign entry point warns exactly once per call and
-    returns results identical to the unified API."""
-
-    def _deprecations(self, w):
-        return [x for x in w if issubclass(x.category, DeprecationWarning)]
-
-    def test_simulate_shim(self):
-        from repro.sim.engine import simulate
-
-        tr = random_trace(30, n_intervals=5)
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            res = simulate(tr, fm_frac=0.5)
-        assert len(self._deprecations(w)) == 1
-        want = run(
-            Experiment(
-                scenarios=[Scenario(trace=tr)],
-                fm_fracs=(0.5,),
-                collect_configs=True,
-            )
-        ).record().result
-        assert_result_equal(res, want)
-
-    def test_sweep_fm_fracs_shim(self):
-        from repro.sim.sweep import sweep_fm_fracs
-
-        tr = random_trace(31, n_intervals=5)
-        fracs = (0.8, 0.4)
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            res = sweep_fm_fracs(tr, fracs, collect_configs=True)
-        assert len(self._deprecations(w)) == 1
-        rs = run(
-            Experiment(
-                scenarios=[Scenario(trace=tr)],
-                fm_fracs=fracs,
-                collect_configs=True,
-            )
-        )
-        for i, f in enumerate(fracs):
-            rec = rs.record(fm_frac=f)
-            assert res.stats[i] == rec.result.stats
-            assert np.array_equal(
-                res.interval_times[i], rec.result.interval_times
-            )
-            assert res.configs[i] == rec.result.configs
-
-    def test_sweep_tuned_shim(self):
-        from repro.sim.sweep import TunedSlice, sweep_tuned
-
-        tr = random_trace(32, n_intervals=16)
-        db = synthetic_db()
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            (res,) = sweep_tuned(
-                tr,
-                [TunedSlice(1.0, live_tuner(db), TUNER_SPEC.tune_every)],
-            )
-        assert len(self._deprecations(w)) == 1
-        rs = run(
-            Experiment(
-                scenarios=[Scenario(trace=tr)],
-                fm_fracs=(1.0,),
-                policies=[PolicySpec(tuner=TUNER_SPEC)],
-            ),
-            db=db,
-        )
-        assert_result_equal(res, rs.record().result)
-
-    def test_sweep_times_shim(self):
-        from repro.sim.sweep import sweep_times
-
-        tr = random_trace(33, n_intervals=5)
-        fracs = (0.9, 0.5, 0.2)
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            times = sweep_times(tr, fracs)
-        assert len(self._deprecations(w)) == 1
-        rs = run(
-            Experiment(scenarios=[Scenario(trace=tr)], fm_fracs=fracs)
-        )
-        assert np.array_equal(times, rs.total_times())
-
-
 class TestPolicyRegistry:
     """The registry is the only policy-routing surface: new kinds ride the
     planner via their capability flags, params round-trip losslessly, and
@@ -888,8 +801,7 @@ class TestPolicyRegistry:
 
 
 class TestChunkedStepScoping:
-    """chunked-loop provenance is scoped per policy instance (and the
-    deprecated module-level shims read a thread-local aggregate), so
+    """chunked-loop provenance is scoped per policy instance, so
     concurrent runs cannot cross-pollute each other's counts."""
 
     def test_per_instance_isolation(self):
@@ -920,44 +832,42 @@ class TestChunkedStepScoping:
         assert rs.chunked_step_count == 0
 
     def test_thread_local_aggregate_isolation(self):
+        """A reference-pool run that loops chunked on another thread
+        leaves a concurrent sweep's provenance at 0, and each thread's
+        policy instance counts only its own loops."""
         import threading
 
-        from repro.tiering import policy as policy_mod
-
         tr = random_trace(52, n_intervals=4)
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            policy_mod.reset_chunked_step_count()
-            worker_counts = {}
+        solo = TPPPolicy()
+        _simulate(
+            tr, fm_frac=0.4, policy=solo, pool_factory=ReferencePagePool
+        )
+        assert solo.chunked_steps > 0
+        start = threading.Barrier(2, timeout=60)
+        counts = {}
 
-            def worker():
-                pol = TPPPolicy()
-                _simulate(
-                    tr, fm_frac=0.4, policy=pol,
-                    pool_factory=ReferencePagePool,
-                )
-                worker_counts["instance"] = pol.chunked_steps
-                worker_counts["tls"] = policy_mod.chunked_step_count()
+        def worker():
+            pol = TPPPolicy()
+            start.wait()
+            _simulate(
+                tr, fm_frac=0.4, policy=pol, pool_factory=ReferencePagePool
+            )
+            counts["worker"] = pol.chunked_steps
 
-            t = threading.Thread(target=worker)
-            t.start()
-            t.join()
-            assert worker_counts["instance"] > 0
-            assert worker_counts["tls"] == worker_counts["instance"]
-            # this thread's aggregate never saw the worker's executions
-            assert policy_mod.chunked_step_count() == 0
-
-    def test_module_shims_deprecated(self):
-        from repro.tiering import policy as policy_mod
-
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            policy_mod.reset_chunked_step_count()
-            policy_mod.chunked_step_count()
-        deps = [
-            x for x in w if issubclass(x.category, DeprecationWarning)
-        ]
-        assert len(deps) == 2
+        t = threading.Thread(target=worker)
+        t.start()
+        start.wait()
+        mine = TPPPolicy()
+        _simulate(
+            tr, fm_frac=0.4, policy=mine, pool_factory=ReferencePagePool
+        )
+        rs = run(
+            Experiment(scenarios=[Scenario(trace=tr)], fm_fracs=(0.5, 0.3))
+        )
+        t.join()
+        assert counts["worker"] == solo.chunked_steps
+        assert mine.chunked_steps == solo.chunked_steps
+        assert rs.chunked_step_count == 0
 
 
 class TestResultCache:

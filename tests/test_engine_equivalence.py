@@ -5,7 +5,7 @@ decay, bulk policy steps) and the batched fm-size sweep engine are pure
 performance work: same-seed simulations must reproduce the seed
 implementation's migration counters (``pgpromote_*``, ``pgdemote_*``,
 ``alloc_*``) and interval times **exactly**, and the batched sweep must
-match per-size ``simulate()`` on every fm fraction. The seed implementation
+match per-size ``_simulate()`` on every fm fraction. The seed implementation
 is kept verbatim as :class:`repro.tiering.reference_pool.ReferencePagePool`
 for exactly this purpose.
 """
@@ -23,8 +23,8 @@ from repro.core.trace import IntervalAccess, Trace
 from repro.core.tuner import TunaTuner, TunerConfig, build_database, scale_config
 from repro.core.watermark import WatermarkController
 from repro.runtime import tracing
-from repro.sim.engine import run_trace, simulate
-from repro.sim.sweep import TunedSlice, sweep_fm_fracs, sweep_tuned
+from repro.sim.engine import _simulate, run_trace
+from repro.sim.sweep import TunedSlice, _sweep_fm_fracs, _sweep_tuned
 from repro.tiering import policy as policy_mod
 from repro.tiering.page_pool import (
     LazyHeat,
@@ -68,47 +68,47 @@ def assert_run_equal(res_a, res_b):
 
 
 class TestIncrementalPoolEquivalence:
-    """simulate() with the incremental pool == seed pool, bit for bit."""
+    """_simulate() with the incremental pool == seed pool, bit for bit."""
 
     @pytest.mark.parametrize("frac", [1.0, 0.9, 0.6, 0.35, 0.15])
     def test_microbench_counters_and_times(self, frac):
         tr = microbench_trace()
-        ref = simulate(tr, fm_frac=frac, pool_factory=ReferencePagePool)
-        new = simulate(tr, fm_frac=frac)
+        ref = _simulate(tr, fm_frac=frac, pool_factory=ReferencePagePool)
+        new = _simulate(tr, fm_frac=frac)
         assert_run_equal(ref, new)
 
     @pytest.mark.parametrize("seed", [0, 1, 2])
     @pytest.mark.parametrize("frac", [0.8, 0.45, 0.2])
     def test_random_traces(self, seed, frac):
         tr = random_trace(seed)
-        ref = simulate(tr, fm_frac=frac, pool_factory=ReferencePagePool)
-        new = simulate(tr, fm_frac=frac)
+        ref = _simulate(tr, fm_frac=frac, pool_factory=ReferencePagePool)
+        new = _simulate(tr, fm_frac=frac)
         assert_run_equal(ref, new)
 
     def test_config_vectors_match(self):
         tr = microbench_trace(n_intervals=8)
-        ref = simulate(tr, fm_frac=0.5, pool_factory=ReferencePagePool)
-        new = simulate(tr, fm_frac=0.5)
+        ref = _simulate(tr, fm_frac=0.5, pool_factory=ReferencePagePool)
+        new = _simulate(tr, fm_frac=0.5)
         assert ref.configs == new.configs
 
     def test_fast_only_variant(self):
         tr = microbench_trace(n_intervals=6)
-        ref = simulate(tr.fast_only(), fm_frac=1.0,
+        ref = _simulate(tr.fast_only(), fm_frac=1.0,
                        pool_factory=ReferencePagePool)
-        new = simulate(tr.fast_only(), fm_frac=1.0)
+        new = _simulate(tr.fast_only(), fm_frac=1.0)
         assert_run_equal(ref, new)
 
 
 class TestSweepEquivalence:
-    """Batched sweep == one simulate() per size (within 1e-9; in practice
+    """Batched sweep == one _simulate() per size (within 1e-9; in practice
     bit-exact, which is what these asserts require)."""
 
     def test_microbench_sweep_matches_per_size(self):
         tr = microbench_trace(n_intervals=8)
         fracs = np.round(np.arange(0.95, 0.14, -0.1), 3)
-        res = sweep_fm_fracs(tr, fracs)
+        res = _sweep_fm_fracs(tr, fracs)
         for i, f in enumerate(fracs):
-            per = simulate(tr, fm_frac=float(f))
+            per = _simulate(tr, fm_frac=float(f))
             assert res.stats[i] == per.stats
             np.testing.assert_allclose(
                 res.interval_times[i], per.interval_times,
@@ -120,9 +120,9 @@ class TestSweepEquivalence:
     def test_random_sweep_matches_reference(self, seed):
         tr = random_trace(seed)
         fracs = np.array([0.85, 0.55, 0.3])
-        res = sweep_fm_fracs(tr, fracs, collect_configs=True)
+        res = _sweep_fm_fracs(tr, fracs, collect_configs=True)
         for i, f in enumerate(fracs):
-            ref = simulate(tr, fm_frac=float(f),
+            ref = _simulate(tr, fm_frac=float(f),
                            pool_factory=ReferencePagePool)
             assert res.stats[i] == ref.stats
             assert np.array_equal(res.interval_times[i], ref.interval_times)
@@ -139,7 +139,7 @@ class TestSweepEquivalence:
         trace = generate_microbench(scale_config(cv, 10_000), n_intervals=8)
         for i, f in enumerate(fracs):
             t = trace.fast_only() if f >= 1.0 - 1e-9 else trace
-            seed_t = simulate(
+            seed_t = _simulate(
                 t, fm_frac=min(float(f), 1.0),
                 pool_factory=ReferencePagePool,
             ).total_time
@@ -154,7 +154,7 @@ class TestSweepEquivalence:
         db_fast = build_database([cv], fm_fracs=fracs, n_intervals=6)
         db_legacy = build_database(
             [cv],
-            lambda trace, f: simulate(trace, fm_frac=f).total_time,
+            lambda trace, f: _simulate(trace, fm_frac=f).total_time,
             fm_fracs=fracs,
             n_intervals=6,
         )
@@ -214,7 +214,7 @@ def assert_tuned_equal(sim_res, sweep_res, sim_tuner, sweep_tuner):
 
 
 class TestTunedSweepEquivalence:
-    """sweep_tuned == one simulate(..., tuner=...) per slice, bit for bit:
+    """_sweep_tuned == one _simulate(..., tuner=...) per slice, bit for bit:
     counters, interval times, config vectors, per-interval fm sizes, tuner
     decisions and watermark event logs."""
 
@@ -226,7 +226,7 @@ class TestTunedSweepEquivalence:
             tuner = make_tuner(db, tau) if tau is not None else None
             per.append(
                 (
-                    simulate(tr, fm_frac=1.0, tuner=tuner, tune_every=te),
+                    _simulate(tr, fm_frac=1.0, tuner=tuner, tune_every=te),
                     tuner,
                 )
             )
@@ -238,7 +238,7 @@ class TestTunedSweepEquivalence:
             TunedSlice(1.0, tuner, te)
             for tuner, (_, te) in zip(tuners, self.SPECS)
         ]
-        return per, list(zip(sweep_tuned(tr, slices), tuners))
+        return per, list(zip(_sweep_tuned(tr, slices), tuners))
 
     def test_random_trace_with_live_watermark_moves(self):
         tr = random_trace(3, n_intervals=30)
@@ -263,20 +263,20 @@ class TestTunedSweepEquivalence:
 
     def test_reference_pool_anchor(self):
         """The frozen seed pool is the golden model for the tuned path too:
-        simulate(tuner=...) over ReferencePagePool == the tuned sweep."""
+        _simulate(tuner=...) over ReferencePagePool == the tuned sweep."""
         tr = random_trace(5, n_intervals=24)
         db = synthetic_db()
         ref_tuner = make_tuner(db, 0.10)
-        ref = simulate(tr, fm_frac=1.0, tuner=ref_tuner, tune_every=2,
+        ref = _simulate(tr, fm_frac=1.0, tuner=ref_tuner, tune_every=2,
                        pool_factory=ReferencePagePool)
         sweep_tuner = make_tuner(db, 0.10)
-        (res,) = sweep_tuned(tr, [TunedSlice(1.0, sweep_tuner, 2)])
+        (res,) = _sweep_tuned(tr, [TunedSlice(1.0, sweep_tuner, 2)])
         assert_tuned_equal(ref, res, ref_tuner, sweep_tuner)
 
     def test_plain_slice_matches_untuned_simulate(self):
         tr = random_trace(6)
-        (res,) = sweep_tuned(tr, [TunedSlice(0.6)])
-        per = simulate(tr, fm_frac=0.6)
+        (res,) = _sweep_tuned(tr, [TunedSlice(0.6)])
+        per = _simulate(tr, fm_frac=0.6)
         assert res.stats == per.stats
         assert np.array_equal(res.interval_times, per.interval_times)
         assert np.array_equal(res.fm_sizes, per.fm_sizes)
@@ -287,9 +287,9 @@ class TestTunedSweepEquivalence:
         tr = random_trace(7, n_intervals=30)
         db = synthetic_db(max_loss=0.02)  # db says everything is safe
         sim_tuner = make_tuner(db, 0.05, max_step_frac=0.2)
-        sim_res = simulate(tr, fm_frac=1.0, tuner=sim_tuner, tune_every=2)
+        sim_res = _simulate(tr, fm_frac=1.0, tuner=sim_tuner, tune_every=2)
         sweep_tuner = make_tuner(db, 0.05, max_step_frac=0.2)
-        (res,) = sweep_tuned(tr, [TunedSlice(1.0, sweep_tuner, 2)])
+        (res,) = _sweep_tuned(tr, [TunedSlice(1.0, sweep_tuner, 2)])
         assert_tuned_equal(sim_res, res, sim_tuner, sweep_tuner)
 
 
@@ -326,19 +326,19 @@ class TestThrashEquivalence:
 
     def _assert_three_lanes(self, tr, fracs, cap=None, kswapd=None):
         sweep_policy = policy_mod.TPPPolicy(hot_thr=4)
-        res = sweep_fm_fracs(
+        res = _sweep_fm_fracs(
             tr, fracs, hw_capacity_pages=cap, kswapd_batch=kswapd,
             collect_configs=True, policy=sweep_policy,
         )
         assert sweep_policy.chunked_steps == 0
         for i, f in enumerate(fracs):
-            ref = simulate(
+            ref = _simulate(
                 tr, fm_frac=float(f), hw_capacity_pages=cap,
                 pool_factory=functools.partial(
                     ReferencePagePool, kswapd_batch=kswapd
                 ),
             )
-            chunked = simulate(
+            chunked = _simulate(
                 tr, fm_frac=float(f), hw_capacity_pages=cap,
                 pool_factory=functools.partial(
                     _ChunkedOnlyPool, kswapd_batch=kswapd
@@ -387,14 +387,14 @@ class TestThrashEquivalence:
         for tau, te in specs:
             tuner = make_tuner(db, tau, max_step_frac=0.3) if tau else None
             per.append(
-                (simulate(tr, fm_frac=0.9, tuner=tuner, tune_every=te), tuner)
+                (_simulate(tr, fm_frac=0.9, tuner=tuner, tune_every=te), tuner)
             )
         tuners = [
             make_tuner(db, tau, max_step_frac=0.3) if tau else None
             for tau, _ in specs
         ]
         sweep_policy = policy_mod.TPPPolicy(hot_thr=4)
-        swept = sweep_tuned(
+        swept = _sweep_tuned(
             tr,
             [TunedSlice(0.9, t, te) for t, (_, te) in zip(tuners, specs)],
             policy=sweep_policy,
@@ -491,7 +491,7 @@ class TestPluggableBackendEquivalence:
 
     def _assert_three_lanes(self, make_policy, tr, fracs, kswapd=None):
         sweep_policy = make_policy()
-        res = sweep_fm_fracs(
+        res = _sweep_fm_fracs(
             tr, fracs, kswapd_batch=kswapd, collect_configs=True,
             policy=sweep_policy,
         )
@@ -500,7 +500,7 @@ class TestPluggableBackendEquivalence:
         for i, f in enumerate(fracs):
             suppressed += sum(c.pm_admit_fail for c in res.configs[i])
             for pf in (ReferencePagePool, _ChunkedOnlyPool):
-                lane = simulate(
+                lane = _simulate(
                     tr, fm_frac=float(f), policy=make_policy(),
                     pool_factory=functools.partial(pf, kswapd_batch=kswapd),
                 )
@@ -532,7 +532,7 @@ class TestPluggableBackendEquivalence:
             tuner = make_tuner(db, tau, max_step_frac=0.3) if tau else None
             per.append(
                 (
-                    simulate(
+                    _simulate(
                         tr, fm_frac=0.9, policy=cls(**params),
                         tuner=tuner, tune_every=te,
                     ),
@@ -544,7 +544,7 @@ class TestPluggableBackendEquivalence:
             for tau, _ in specs
         ]
         sweep_policy = cls(**params)
-        swept = sweep_tuned(
+        swept = _sweep_tuned(
             tr,
             [TunedSlice(0.9, t, te) for t, (_, te) in zip(tuners, specs)],
             policy=sweep_policy,
@@ -585,8 +585,8 @@ class TestPluggableBackendEquivalence:
         immediately preceding step, where same-regime ping-pong lives)."""
         tr = pressure_trace(9, rss=3_000, n_intervals=8)
         guard = policy_mod.ThrashGuardPolicy(reuse_window=reuse_window)
-        res = simulate(tr, fm_frac=0.3, policy=guard)
-        tpp = simulate(tr, fm_frac=0.3)
+        res = _simulate(tr, fm_frac=0.3, policy=guard)
+        tpp = _simulate(tr, fm_frac=0.3)
         suppressed = sum(c.pm_admit_fail for c in res.configs)
         assert suppressed > 0
         assert res.migrations < tpp.migrations
@@ -621,9 +621,9 @@ class TestBatchPolicySchedule:
 
         tr = random_trace(9)
         fracs = np.array([0.9, 0.5, 0.25])
-        res = sweep_fm_fracs(tr, fracs)  # drives step_batch internally
+        res = _sweep_fm_fracs(tr, fracs)  # drives step_batch internally
         for i, f in enumerate(fracs):
-            per = simulate(tr, fm_frac=float(f),
+            per = _simulate(tr, fm_frac=float(f),
                            policy=TPPPolicy(hot_thr=4))
             assert res.stats[i] == per.stats
             assert np.array_equal(res.interval_times[i], per.interval_times)
@@ -712,7 +712,6 @@ class TestJaxSweepEquivalence:
     def _assert_three_lanes(self, tr, fracs, cap=None, kswapd=None,
                             make_policy=None):
         pytest.importorskip("jax")
-        from repro.sim.sweep import _sweep_fm_fracs
 
         if make_policy is None:
             def make_policy():
@@ -735,7 +734,7 @@ class TestJaxSweepEquivalence:
                 jx.interval_times[i], base.interval_times[i]
             ), f
             assert jx.configs[i] == base.configs[i], f
-            ref = simulate(
+            ref = _simulate(
                 tr, fm_frac=float(f), hw_capacity_pages=cap,
                 policy=make_policy(),
                 pool_factory=functools.partial(
@@ -828,7 +827,6 @@ class TestJaxSweepEquivalence:
 
     def test_tuned_shrink_three_lanes(self):
         pytest.importorskip("jax")
-        from repro.sim.sweep import _sweep_tuned
 
         tr = pressure_trace(5, rss=4_000, n_intervals=12)
         db = synthetic_db(rss=4_000)
@@ -853,7 +851,7 @@ class TestJaxSweepEquivalence:
             tuners[engine] = tn
         ref_tuners = mk()
         refs = [
-            simulate(tr, fm_frac=0.9, tuner=t, tune_every=te,
+            _simulate(tr, fm_frac=0.9, tuner=t, tune_every=te,
                      pool_factory=ReferencePagePool)
             for t, (_, te) in zip(ref_tuners, specs)
         ]

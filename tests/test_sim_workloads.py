@@ -30,7 +30,7 @@ needs_hypothesis = pytest.mark.skipif(
 from repro.core.microbench import generate_microbench, spec_from_config
 from repro.core.telemetry import ConfigVector
 from repro.core.trace import load_trace, save_trace
-from repro.sim.engine import run_trace, simulate
+from repro.sim.engine import _simulate, run_trace
 from repro.sim.workloads import WORKLOADS, arrivals_trace, bfs_trace
 from repro.sim.workloads.arrivals import (
     modulated_rates,
@@ -82,8 +82,8 @@ class TestTraces:
         # the one-time migration cost; benchmarks/fig1 covers it at full
         # scale and run length.)
         tr = small_traces["bfs"]
-        tpp = simulate(tr, fm_frac=0.6)
-        ft = simulate(tr, fm_frac=0.6, policy=FirstTouchPolicy())
+        tpp = _simulate(tr, fm_frac=0.6)
+        ft = _simulate(tr, fm_frac=0.6, policy=FirstTouchPolicy())
         slow_tpp = sum(c.pacc_s for c in tpp.configs[len(tpp.configs) // 2:])
         slow_ft = sum(c.pacc_s for c in ft.configs[len(ft.configs) // 2:])
         assert tpp.migrations > 0

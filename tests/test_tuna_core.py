@@ -15,7 +15,7 @@ from repro.core import (
 )
 from repro.core.microbench import spec_from_config
 from repro.core.tuner import build_database
-from repro.sim.engine import run_trace, simulate
+from repro.sim.engine import _simulate, run_trace
 
 
 def mk_cv(pacc_f=200000, pacc_s=4000, pm=60, ai=6.0, rss=60000, hot_thr=4, nt=1):
@@ -49,7 +49,7 @@ class TestMicrobenchEquations:
         under TPP at the reference size reproduces pacc/pm/AI."""
         cv = mk_cv()
         trace = generate_microbench(cv, n_intervals=12)
-        res = simulate(trace, fm_frac=0.9)
+        res = _simulate(trace, fm_frac=0.9)
         mid = res.configs[8]  # steady-state interval
         assert mid.pm_pr == pytest.approx(cv.pm_pr, rel=0.1)
         assert mid.pm_de == pytest.approx(cv.pm_de, rel=0.1)
@@ -60,7 +60,7 @@ class TestMicrobenchEquations:
     def test_fast_only_variant_has_no_slow_accesses(self):
         cv = mk_cv()
         trace = generate_microbench(cv, n_intervals=8)
-        res = simulate(trace.fast_only(), fm_frac=1.0)
+        res = _simulate(trace.fast_only(), fm_frac=1.0)
         assert all(c.pacc_s == 0 for c in res.configs)
         assert res.migrations == 0
 
