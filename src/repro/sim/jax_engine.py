@@ -47,17 +47,18 @@ Exactness contract (pinned by ``tests/test_engine_equivalence.py``):
 Thrash-regime victim resolution stays host-side by design: the commit step
 detects interference (reclaim demand reaching into same-step promotions)
 per size and commits a provisional fast-path state; interfering sizes are
-then corrected through the *same* host resolver the numpy sweep uses
-(:func:`repro.tiering.page_pool._resolve_step_victims` over the schedule's
-replayed availability horizons) and a tiny fix-up scatter. Counters are
+then corrected on the host from the schedule's replayed availability
+horizons and patched in with a tiny fix-up scatter. Counters are
 schedule-determined and identical either way, so only tier identity is
-patched. The resolver reads its key order from the shared ranking: both
-streams are keyed on integer ranks, and a size's winners come in key order
-as the interval's hot set in rank order filtered by that size's winners (a
-size with few winners sorts their ranks instead), so no size sorts heat.
-Where the device ranked, an interval with an interfering size pulls the
-permutation and the hot set's ranks once for this; other intervals pull
-nothing of the ranking.
+patched. The resolver is this module's own rank-keyed merge
+(:func:`_resolve_step_victims`): both streams are keyed on integer ranks
+of the shared ranking, each reclaim event is a k-selection over arrival
+groups, and no size sorts heat or forms its winners' key order. The numpy
+sweep keeps its float-key event replay
+(:func:`repro.tiering.page_pool._resolve_step_victims`), so the oracle
+shares no merge code with this path. Where the device ranked, an interval
+with an interfering size pulls the permutation and the hot set's ranks
+once for this; other intervals pull nothing of the ranking.
 
 Eligibility (enforced here, routed by :mod:`repro.sim.api`): the policy
 must advertise ``jax_batchable`` (TPP and the trace-pure admission
@@ -88,21 +89,12 @@ from repro.kernels.ops import pallas_mode
 from repro.runtime import tracing
 from repro.sim.costmodel import absorb_cache, effective_mlp, interval_time
 from repro.sim.sweep import _fold_heat, _hot_sorted
-from repro.tiering.page_pool import (
-    GlobalDemoteRank,
-    LazyHeat,
-    Tier,
-    TieredPagePool,
-    _resolve_step_victims,
-)
+from repro.tiering.page_pool import GlobalDemoteRank, LazyHeat, Tier, TieredPagePool
 from repro.tiering.policy import PolicyOutcome
 
 _FAST = int(Tier.FAST)
 _SLOW = int(Tier.SLOW)
 _NO_GROUP = np.iinfo(np.int32).max  # tie group of "no winner"
-# an interfering size sorts its winners' ranks itself while fewer than
-# 1/_OWN_ORDER_CUT of the hot set win, and else filters the shared order
-_OWN_ORDER_CUT = 3
 
 
 def _bucket(n: int, floor: int = 128) -> int:
@@ -399,6 +391,106 @@ def _tie_groups(g: GlobalDemoteRank) -> np.ndarray:
     return grp
 
 
+def _resolve_step_victims(
+    base_rank: np.ndarray, cand_rank: np.ndarray, events: list
+) -> tuple[int, np.ndarray]:
+    """Victim identities of an interfering size's step, keyed on ranks.
+
+    The same answer as :func:`repro.tiering.page_pool._resolve_step_victims`
+    (the numpy sweep's event replay): each reclaim event ``(p, d)`` of
+    ``events`` demotes the ``d`` smallest available keys, where the base
+    stream ``base_rank`` (the pre-step fast tier's ranks, ascending) is
+    available from the start and the winner ``cand_rank[i]`` (promotion
+    order) from the first event with ``p > i``. Keys are distinct ranks
+    of the interval's shared ranking, so they compare exactly like the
+    (effective heat, page id) tuples.
+
+    An *arrival group* is the base stream, or the winners promoted between
+    two consecutive horizons. All members of a group become available
+    together and the smallest are taken first, so what a group has lost
+    is always a key prefix of it: the state is one taken count per group.
+    The groups are sorted once, each with its own key offset, into one
+    flat array; an event is then a k-selection, the threshold key ``k`` at
+    which the arrived groups hold ``d`` untaken members, found by a
+    safeguarded interpolation search whose every probe is one vectorised
+    ``searchsorted`` over the groups. The cost per size is a sort of the
+    winners by group plus a few probes per event; nothing is inserted and
+    the winners' global key order is never formed.
+
+    Returns ``(n_base, cand_taken)``: the step demotes the first
+    ``n_base`` base entries and the winners under the mask
+    ``cand_taken`` (promotion order).
+    """
+    cand_taken = np.zeros(cand_rank.size, dtype=bool)
+    if not events:
+        return 0, cand_taken
+    horizon = np.fromiter((p for p, _ in events), np.int64, len(events))
+    n_base = base_rank.size
+    p_end = int(horizon[-1])  # later winners arrive after the last event
+    span = 1 + max(
+        int(base_rank.max(initial=-1)), int(cand_rank[:p_end].max(initial=-1))
+    )
+    dtype = np.int32 if (len(events) + 1) * span < 2**31 else np.int64
+    off = np.arange(len(events) + 1, dtype=dtype) * span
+    # group 0 is the base stream, group e + 1 the winners that arrive just
+    # before event e; each is sorted and lifted by its offset in place
+    flat = np.empty(n_base + p_end, dtype=dtype)
+    flat[:n_base] = base_rank
+    flat[n_base:] = cand_rank[:p_end]
+    end = n_base + np.concatenate(([0], horizon))
+    start = np.concatenate(([0], end[:-1]))
+    for g in range(1, len(events) + 1):
+        seg = flat[start[g] : end[g]]
+        seg.sort()
+        seg += off[g]
+    taken = start.copy()  # each group's first untaken member, in ``flat``
+    k = 0
+    for e, (_, d) in enumerate(events):
+        g = e + 2  # the groups that have arrived
+        q, tk = off[:g], taken[:g]
+        c_hi = int((end[:g] - tk).sum())
+        if c_hi <= d:
+            tk[:] = end[:g]
+            continue
+        # the count of untaken keys <= k steps by one per key: search for
+        # a k where it is exactly d, starting from the last event's k
+        # (thresholds tend to climb), by interpolation between bracket
+        # ends (the Illinois rule halves a stale end), bisecting where the
+        # bracket stops halving
+        lo, hi = -1, span - 1
+        f_lo, f_hi = -d - 0.5, c_hi - d - 0.5
+        side = stall = 0
+        while True:
+            w = hi - lo
+            if stall >= 3:
+                k, stall = lo + w // 2, 0
+            elif side or not e:
+                k = lo + int(-f_lo * w / (f_hi - f_lo))
+            k = min(max(k, lo + 1), hi - 1)
+            idx = flat.searchsorted(q + k, side="right")
+            c = int(np.maximum(idx - tk, 0).sum())
+            if c == d:
+                break
+            if c > d:
+                hi, f_hi = k, c - d - 0.5
+                if side > 0:
+                    f_lo /= 2
+                side = 1
+            else:
+                lo, f_lo = k, c - d - 0.5
+                if side < 0:
+                    f_hi /= 2
+                side = -1
+            stall = stall + 1 if 2 * (hi - lo) > w else 0
+        np.maximum(tk, idx, out=tk)
+    # a group's taken members are its smallest: compare against the largest
+    for g in range(1, len(events) + 1):
+        if taken[g] > start[g]:
+            a, b = start[g] - n_base, end[g] - n_base
+            cand_taken[a:b] = cand_rank[a:b] <= flat[taken[g] - 1] - off[g]
+    return int(taken[0]), cand_taken
+
+
 @tracing.traced("sweep")
 def _sweep_run_jax(
     trace,
@@ -419,9 +511,9 @@ def _sweep_run_jax(
     return, bit-exact results; see the module docstring for the contract.
     The layers are :mod:`repro.runtime.tracing` spans (``sweep.*``,
     ``interval.*``, ``fixup.*``) with counters of intervals, touched and
-    hot pages, migrating sizes, commit steps, interfering sizes (and which
-    key order each took), device dispatches and host<->device bytes; they
-    time and count, and feed nothing back.
+    hot pages, migrating sizes, commit steps, interfering sizes (and the
+    reclaim events their merges took), device dispatches and host<->device
+    bytes; they time and count, and feed nothing back.
     """
     with tracing.span("sweep.eligibility"):
         _require_jax_runnable(trace, policy, faults)
@@ -631,17 +723,15 @@ def _sweep_run_jax(
                     if tracing.active():
                         tracing.count("xfer.d2h_bytes", interf.nbytes)
                     # --- thrash regime: resolve interfering sizes' victim
-                    # identities with the numpy sweep's own host resolver
-                    # and patch the device tier (counters are
+                    # identities on the host with the rank-keyed merge and
+                    # patch the device tier (counters are
                     # schedule-determined and already exact)
                     with tracing.span("interval.fixup"):
                         interfering = np.flatnonzero(interf)
                         if interfering.size:
                             # what the resolver reads of the ranking: the
-                            # permutation, and the hot set's ranks and its
-                            # positions in (effective heat, page id) order
-                            # (every interfering size's candidate order is
-                            # a filter of it)
+                            # permutation, and the hot set's ranks (the
+                            # keys of every interfering size's winners)
                             with tracing.span("interval.pull"):
                                 rank_order = np.asarray(order)
                                 hot_slot = np.asarray(hot_slot)
@@ -662,8 +752,7 @@ def _sweep_run_jax(
                                 tracing.count("xfer.d2h_bytes", vsel.nbytes + win.nbytes)
                             vrank = np.flatnonzero(vsel)  # walk order = rank order
                             victims = rank_order[vrank]
-                            won = win[: hot.size]
-                            widx = np.flatnonzero(won)  # promotion order
+                            widx = np.flatnonzero(win[: hot.size])  # promotion order
                             winners = hot[widx]
                             if victims.size + winners.size < d_demand[s]:
                                 raise RuntimeError(
@@ -672,20 +761,9 @@ def _sweep_run_jax(
                                 )
                             with tracing.span("fixup.merge", size=s):
                                 step_events = pools[s]._schedule_events(int(n_cand[s]))
-                                # the merge keys on ranks; the winners' key
-                                # order is read off the shared ranking, or
-                                # sorted where they are few beside it
-                                wrank = hot_rank[widx]
-                                if widx.size * _OWN_ORDER_CUT < hot.size:
-                                    tracing.count("fixup.own_order")
-                                    worder = np.argsort(wrank)
-                                else:
-                                    tracing.count("fixup.shared_order")
-                                    promo = np.empty(hot.size, dtype=np.int64)
-                                    promo[widx] = np.arange(widx.size)
-                                    worder = promo[hot_by_key[won[hot_by_key]]]
+                                tracing.count("fixup.merge_events", len(step_events))
                                 base_n, cand_taken = _resolve_step_victims(
-                                    vrank, victims, wrank, winners, step_events, worder
+                                    vrank, hot_rank[widx], step_events
                                 )
                             with tracing.span("fixup.patch", size=s):
                                 fix = np.zeros(num_pages, dtype=np.int8)

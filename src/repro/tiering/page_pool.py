@@ -567,7 +567,6 @@ def _resolve_step_victims(
     cand_eff: np.ndarray,
     cand_ids: np.ndarray,
     events: list,
-    cand_order: np.ndarray | None = None,
 ) -> tuple[int, np.ndarray]:
     """Victim identities for a bulk step whose reclaim demand reaches into
     the same step's promotions (the thrash regime).
@@ -593,18 +592,14 @@ def _resolve_step_victims(
     at each availability horizon. No per-page replay, no tier writes —
     the caller commits both streams' victims in single array operations.
 
-    ``cand_order``, when given, is the candidates' promotion indices in
-    ascending key order, and the key sort is skipped. A caller holding
-    the interval's :class:`GlobalDemoteRank` passes integer ranks as both
-    streams' keys (``*_eff``) with the order read off the shared ranking:
-    ranks are distinct, so they compare exactly like the (effective heat,
-    page id) tuples.
+    This is the numpy sweep's merge and the oracle of the device sweep's
+    own rank-keyed one (:func:`repro.sim.jax_engine._resolve_step_victims`).
 
     Returns ``(n_base, cand_taken)``: the step demotes
     ``base_ids[:n_base]`` and ``cand_ids[cand_taken]`` (mask in
     promotion order).
     """
-    order = np.lexsort((cand_ids, cand_eff)) if cand_order is None else cand_order
+    order = np.lexsort((cand_ids, cand_eff))
     inv = np.empty(order.size, dtype=np.int64)
     inv[order] = np.arange(order.size, dtype=np.int64)
     s_eff = cand_eff[order]

@@ -413,12 +413,12 @@ class TestThrashEquivalence:
     @pytest.mark.parametrize("keys", ["float", "rank"])
     @pytest.mark.parametrize("seed", list(range(8)))
     def test_victim_resolver_matches_event_replay(self, seed, keys):
-        """Property check of the merge itself: random key-sorted base
+        """Property check of the merges themselves: random key-sorted base
         streams, random candidate keys and random availability horizons
         must select exactly the pages a per-event heap replay demotes —
-        keyed on float heat (the resolver sorts the candidates), or on
-        integer ranks of one shared (heat, id) ranking with the
-        candidates' key order handed in (the device sweep's form)."""
+        keyed on float heat (the numpy sweep's event replay), or on
+        integer ranks of one shared (heat, id) ranking (the device
+        sweep's arrival-group merge)."""
         rng = np.random.default_rng(seed)
         n_base = int(rng.integers(0, 60))
         n_cand = int(rng.integers(1, 60))
@@ -441,37 +441,103 @@ class TestThrashEquivalence:
                 demanded += d
         if not events:
             events = [(n_cand, max(1, (n_base + n_cand) // 2))]
+        base_key = list(zip(base_eff, base_ids.tolist()))
+        cand_key = list(zip(cand_eff, cand_ids.tolist()))
         if keys == "float":
-            args = (base_eff, base_ids, cand_eff, cand_ids, events)
+            n_b, taken = _resolve_step_victims(
+                base_eff, base_ids, cand_eff, cand_ids, events
+            )
         else:
             ranking = np.lexsort((np.concatenate([base_ids, cand_ids]),
                                   np.concatenate([base_eff, cand_eff])))
             rank = np.empty(ranking.size, dtype=np.int64)
             rank[ranking] = np.arange(ranking.size)
-            # the candidates' key order is a filter of the shared ranking
-            cand_order = ranking[ranking >= n_base] - n_base
-            args = (rank[:n_base], base_ids, rank[n_base:], cand_ids, events,
-                    cand_order)
-        n_b, taken = _resolve_step_victims(*args)
-        # naive replay: per event, pop the d smallest available (eff, id)
-        heap, bi, p_prev = [], 0, 0
-        got_base, got_cand = 0, set()
-        for p_e, d in events:
-            for j in range(p_prev, p_e):
-                heapq.heappush(heap, (cand_eff[j], int(cand_ids[j]), j))
-            p_prev = p_e
-            for _ in range(d):
-                take_base = bi < n_base and (
-                    not heap
-                    or (base_eff[bi], int(base_ids[bi])) < heap[0][:2]
-                )
-                if take_base:
-                    got_base += 1
-                    bi += 1
-                elif heap:
-                    got_cand.add(heapq.heappop(heap)[2])
-        assert n_b == got_base, (seed, events)
-        assert set(np.flatnonzero(taken)) == got_cand, (seed, events)
+            n_b, taken = _rank_merge()(rank[:n_base], rank[n_base:], events)
+        assert (n_b, set(np.flatnonzero(taken))) == _heap_replay(
+            base_key, cand_key, events
+        ), (seed, events)
+
+    @pytest.mark.parametrize(
+        "base, cand, events",
+        [
+            # no reclaim event: nothing is demoted
+            ([0, 2, 5], [1, 3, 4], []),
+            # consecutive events with the same horizon: an empty arrival
+            # group between them
+            ([1, 4, 7, 12], [0, 6, 3, 9, 2], [(2, 1), (2, 2), (2, 1), (5, 2)]),
+            # late arrivals whose ranks lie below every pending rank
+            ([10, 11, 30], [20, 25, 21, 1, 0, 2], [(3, 2), (3, 1), (6, 2)]),
+            # the base stream runs out before the last event
+            ([0, 1], [5, 2, 8, 3], [(0, 1), (2, 2), (4, 2)]),
+            # an event that takes only winners
+            ([20, 21, 22], [3, 1, 2, 40], [(3, 3), (4, 2)]),
+        ],
+        ids=["no_events", "same_horizon", "late_low_arrivals",
+             "base_exhausted", "winners_only"],
+    )
+    def test_rank_merge_edge_cases(self, base, cand, events):
+        """The device sweep's rank merge at the arrival-group edges:
+        each case against the per-event heap replay."""
+        base_rank = np.asarray(base, dtype=np.int64)
+        cand_rank = np.asarray(cand, dtype=np.int64)
+        n_b, taken = _rank_merge()(base_rank, cand_rank, events)
+        assert taken.shape == cand_rank.shape and taken.dtype == bool
+        assert (n_b, set(np.flatnonzero(taken))) == _heap_replay(
+            [(int(r), 0) for r in base], [(int(r), 0) for r in cand], events
+        )
+
+    def test_rank_merge_matches_float_merge_at_scale(self):
+        """At 50,000 pages, with the reclaim events of a real TPP schedule
+        near capacity, the rank merge agrees with the numpy sweep's float
+        merge on (effective heat, page id) keys derived from the ranks."""
+        rng = np.random.default_rng(11)
+        n_pages = cap = 50_000
+        fm = int(0.3 * cap)
+        low = cap - fm
+        events = []
+        pm_pr, _, _, _, _, d_demand = _bulk_schedule(
+            low, fm, int(0.8 * low), low, low, cap // 64, n_pages - fm,
+            events_out=events,
+        )
+        assert len(events) > 10 and pm_pr > 0
+        n_base = min(d_demand, fm)
+        perm = rng.permutation(n_pages)
+        base_rank = np.sort(perm[:n_base])
+        cand_rank = perm[n_base : n_base + pm_pr]
+        # keys with heavy heat ties that the page id breaks: (r // 7, r % 7)
+        # orders exactly like r
+        n_b, taken = _rank_merge()(base_rank, cand_rank, events)
+        f_b, f_taken = _resolve_step_victims(
+            (base_rank // 7).astype(np.float64), base_rank % 7,
+            (cand_rank // 7).astype(np.float64), cand_rank % 7, events,
+        )
+        assert n_b == f_b and np.array_equal(taken, f_taken)
+        assert 0 < n_b < n_base and 0 < taken.sum() < pm_pr
+
+
+def _rank_merge():
+    pytest.importorskip("jax")
+    from repro.sim.jax_engine import _resolve_step_victims as rank_merge
+
+    return rank_merge
+
+
+def _heap_replay(base_key, cand_key, events):
+    """Naive replay of a thrash step: per event, pop the ``d`` smallest
+    available keys. Returns the base entries and candidates demoted."""
+    heap, bi, p_prev = [], 0, 0
+    got_base, got_cand = 0, set()
+    for p_e, d in events:
+        for j in range(p_prev, p_e):
+            heapq.heappush(heap, (cand_key[j], j))
+        p_prev = p_e
+        for _ in range(d):
+            if bi < len(base_key) and (not heap or base_key[bi] < heap[0][0]):
+                got_base += 1
+                bi += 1
+            elif heap:
+                got_cand.add(heapq.heappop(heap)[1])
+    return got_base, got_cand
 
 
 BACKEND_CASES = [
@@ -754,11 +820,21 @@ class TestJaxSweepEquivalence:
         )
 
     @pytest.mark.parametrize("seed", [0, 2])
-    def test_thrash_resolver_key_orders(self, seed):
-        """Each interfering size takes its winners' key order from the
-        interval's shared ranking, or sorts their ranks itself where few
-        win: the pressure trace's sizes reach both, every resolver call
-        takes one, and the sweep stays bit-identical either way."""
+    def test_thrash_resolver_merge_events(self, monkeypatch, seed):
+        """Every interfering size's rank merge takes its size's reclaim
+        events: ``fixup.merge_events`` counts exactly the events the
+        resolver calls were handed, and the sweep stays bit-identical."""
+        pytest.importorskip("jax")
+        from repro.sim import jax_engine
+
+        resolver = jax_engine._resolve_step_victims
+        handed = []
+
+        def counted(*args):
+            handed.append(len(args[2]))
+            return resolver(*args)
+
+        monkeypatch.setattr(jax_engine, "_resolve_step_victims", counted)
         tracing.reset()
         try:
             with tracing.recording():
@@ -769,10 +845,8 @@ class TestJaxSweepEquivalence:
             c = tracing.snapshot()["counters"]
         finally:
             tracing.reset()
-        shared = c.get("fixup.shared_order", 0)
-        own = c.get("fixup.own_order", 0)
-        assert shared + own == c["sweep.interfering_sizes"]
-        assert shared > 0 and own > 0
+        assert len(handed) == c["sweep.interfering_sizes"] > 0
+        assert c["fixup.merge_events"] == sum(handed) > 0
 
     @pytest.mark.parametrize("on_device", [True, False], ids=["device", "host"])
     @pytest.mark.parametrize("seed", [0, 2])
